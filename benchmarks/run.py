@@ -45,6 +45,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import kernel_bench, paper_tables, serving_bench
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     rows: list = []
     sections = [

@@ -22,6 +22,7 @@ import numpy as np  # noqa: E402
 
 from repro.configs import get_smoke_config  # noqa: E402
 from repro.distributed import pipeline as pp  # noqa: E402
+from repro.launch.mesh import _make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.models.transformer import _attn_block_fwd  # noqa: E402
 
@@ -34,7 +35,7 @@ def main() -> None:
     cfg = dataclasses.replace(cfg, n_layers=N_STAGES * 3)  # 6 partitions x 3 layers
     params = T.init_params(jax.random.PRNGKey(0), cfg)
 
-    mesh = jax.make_mesh((N_STAGES,), ("stage",))
+    mesh = _make_mesh((N_STAGES,), ("stage",))
     staged = pp.reshape_to_stages(params["blocks"], N_STAGES)
     # mode="none": scheduling exactness check without fake-quant rounding
     fwd = pp.make_pipeline_forward(cfg, mesh, N_STAGES, N_MICRO, axis="stage", mode="none")

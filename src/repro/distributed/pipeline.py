@@ -21,10 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6: public top-level export
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -82,22 +79,13 @@ def make_pipeline_forward(cfg: ModelConfig, mesh, n_stages: int, n_micro: int,
         final = jax.lax.dynamic_slice_in_dim(outs, n_stages - 1, n_micro, axis=0)
         return final[None]  # (1, n_micro, mb, s, d) per stage
 
-    try:  # new API spells the replication check check_vma ...
-        fn = shard_map(
-            pipelined_local,
-            mesh=mesh,
-            in_specs=(P(axis), P()),
-            out_specs=P(axis),
-            check_vma=False,
-        )
-    except TypeError:  # ... jax 0.4.x spells it check_rep
-        fn = shard_map(
-            pipelined_local,
-            mesh=mesh,
-            in_specs=(P(axis), P()),
-            out_specs=P(axis),
-            check_rep=False,
-        )
+    fn = shard_map(
+        pipelined_local,
+        mesh=mesh,
+        in_specs=(P(axis), P()),
+        out_specs=P(axis),
+        check_vma=False,
+    )
 
     def pipelined(staged_params, x):
         outs = fn(staged_params, x)  # (S, n_micro, mb, s, d)

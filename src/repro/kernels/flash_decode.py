@@ -95,7 +95,9 @@ def _rope_rows(x, pos, rope_dims: int, theta: float):
     rd = rope_dims
     half = rd // 2
     base = x[:, d - rd:]
-    two_i = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1) * 2.0
+    # Mosaic builds integer iotas only; the cast keeps 2i exact in f32
+    two_i = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+        jnp.float32) * 2.0
     freqs = 1.0 / (theta ** (two_i / rd))  # (1, half)
     ang = pos.astype(jnp.float32) * freqs  # (rows, half)
     cos = jnp.cos(ang)

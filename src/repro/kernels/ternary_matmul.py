@@ -65,18 +65,23 @@ from repro.core.ternary import EPS
 
 
 def _decode2_block(wp: jax.Array) -> jax.Array:
-    """(bk/4, bn) uint8 -> (bk, bn) int8 trits (2-bit codes, LSB=+, MSB=-)."""
+    """(bk/4, bn) uint8 -> (bk, bn) int8 trits (2-bit codes, LSB=+, MSB=-).
+
+    The shifts, masks and the subtraction run in int32 and only the trits
+    narrow to int8: Mosaic has no 8-bit vector shift or subtract."""
+    v = wp.astype(jnp.int32)
     parts = []
     for i in range(packing.PACK2_GROUP):
-        c = (wp >> (2 * i)) & 0b11
-        parts.append(((c & 1).astype(jnp.int8) - ((c >> 1) & 1).astype(jnp.int8)))
+        c = v >> (2 * i)
+        parts.append(((c & 1) - ((c >> 1) & 1)).astype(jnp.int8))
     stacked = jnp.stack(parts, axis=1)  # (bk/4, 4, bn)
     return stacked.reshape(stacked.shape[0] * packing.PACK2_GROUP, stacked.shape[2])
 
 
 def _decode243_block(wp: jax.Array) -> jax.Array:
-    """(bk/5, bn) uint8 -> (bk, bn) int8 trits via repeated divmod-3."""
-    v = wp.astype(jnp.int16)
+    """(bk/5, bn) uint8 -> (bk, bn) int8 trits via repeated divmod-3,
+    in int32 (Mosaic has no 16-bit vector remainder or division)."""
+    v = wp.astype(jnp.int32)
     parts = []
     for _ in range(packing.PACK243_GROUP):
         parts.append((v % 3 - 1).astype(jnp.int8))

@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import Engine
 
@@ -38,14 +39,14 @@ def _serve_fleet(cfg, params, args) -> None:
     hot_cap = min(args.hot_cap, max_len // 2)
     replicas = []
     for i in range(args.replicas):
-        devs = replica_devices(i, args.replicas)
+        dev = replica_devices(i, args.replicas)[0]
         # sync_every=2 keeps router ticks fine-grained: health checks,
         # chaos injection and migration all happen at tick boundaries
         eng = Engine(cfg, params, hot_cap=hot_cap, max_len=max_len,
                      slots=max(2, args.batch // args.replicas),
-                     prefill_chunk=8, paged=True, sync_every=2)
+                     prefill_chunk=8, paged=True, sync_every=2, device=dev)
         replicas.append(Replica(f"r{i}", eng))
-        print(f"replica r{i}: devices {[str(d) for d in devs]}")
+        print(f"replica r{i}: device {dev}")
     rng = np.random.RandomState(1)
     reqs = [
         Request(rid=i,
@@ -97,6 +98,7 @@ def main() -> None:
                     help="fleet chaos: per-tick replica-stall probability")
     ap.add_argument("--chaos-seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = dataclasses.replace(

@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.training import loop as train_loop
 from repro.training.optimizer import AdamWConfig
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--opt-8bit", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     opt = AdamWConfig(

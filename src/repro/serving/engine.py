@@ -329,8 +329,13 @@ class Engine:
         spec_force: Optional[str] = None,
         guard: Optional[PreemptionGuard] = None,
         integrity: Optional[sdc_lib.IntegrityConfig] = None,
+        device: Optional[jax.Device] = None,
     ):
         self.cfg = cfg
+        # the chip that holds this engine's params and state (default:
+        # JAX's default device). Committed arrays pin every jitted step to
+        # it, so data-parallel replicas in one process each use their own.
+        self.device = device
         # Freeze to ROM form once (packed trits + fused wqkv/wgu/w_dqkv/w_gu
         # projection groups, models/pack.py); never reloaded afterwards. The
         # decode hot loop then runs the packed fast path (core/bitlinear.
@@ -345,7 +350,7 @@ class Engine:
         self.max_len = max_len
         self.sample = sample
         self.temperature = temperature
-        self.key = jax.random.PRNGKey(seed)
+        self.key = self._place(jax.random.PRNGKey(seed))
         self.slots = slots
         self.sync_every = sync_every
         # chunked-prefill admission (docs/serving.md): 0 keeps the legacy
@@ -421,7 +426,7 @@ class Engine:
                 )
                 spec = False
         self.spec = spec
-        self.draft_params = (
+        self.draft_params = self._place(
             pack_lib.pack_params(draft_params, draft_cfg) if (spec and pack)
             else (draft_params if spec else None)
         )
@@ -461,6 +466,7 @@ class Engine:
                 path: np.asarray(pw.packed).copy()
                 for path, pw in pack_lib.iter_packed_leaves(self.params)
             }
+        self.params = self._place(self.params)
         self.last_drained: Optional[List[Request]] = None
         self._cancel_requested: Set[int] = set()
         self.last_stats: Optional[ServeStats] = None  # of the last serve()
@@ -481,6 +487,10 @@ class Engine:
                 hot_cap=self.hot_cap, max_len=self.max_len, mode=self.mode,
             )
         )
+
+    def _place(self, tree):
+        """Commit ``tree`` to this engine's device (no-op without one)."""
+        return tree if self.device is None else jax.device_put(tree, self.device)
 
     def _chunked_capable(self) -> bool:
         """Chunked prefill needs a pure attention-token path: per-slot
@@ -558,7 +568,7 @@ class Engine:
             # XLA rejects donating one buffer through several arguments
             return jnp.zeros((n_slots,), jnp.int32)
 
-        return DecodeState(
+        return self._place(DecodeState(
             cache=cache,
             tok=z(),
             key=sub,
@@ -573,7 +583,7 @@ class Engine:
             drafted=z(),
             accepted=z(),
             numerics_bad=jnp.zeros((n_slots,), bool),
-        )
+        ))
 
     def _cache_batch_axes(self):
         """Pytree (matching the cache) of each leaf's batch axis, found by
@@ -1468,7 +1478,8 @@ class Engine:
             leaf = sdc_lib.get_leaf(self.params, path)
             self.params = sdc_lib.set_leaf(
                 self.params, path,
-                dataclasses.replace(leaf, packed=jnp.asarray(gold)))
+                dataclasses.replace(
+                    leaf, packed=self._place(jnp.asarray(gold))))
             self.weight_loads += 1
             ctx.stats.weight_reloads += 1
         self.weight_fault_strikes += 1

@@ -4,6 +4,7 @@ Needs >1 local device, so the heavy check runs in a subprocess with
 XLA_FLAGS set before jax imports (the main pytest process keeps 1 device).
 """
 
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -29,7 +30,7 @@ def test_pipeline_matches_plain_forward_subprocess():
         capture_output=True,
         text=True,
         timeout=900,
-        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "pipelined forward == plain forward" in r.stdout

@@ -809,3 +809,23 @@ def test_replica_devices_partitions_evenly():
     assert replica_devices(3, 4, [0, 1]) == (1,)
     with pytest.raises(ValueError):
         replica_devices(2, 2, devs)
+
+
+def test_engine_device_commits_params_and_state(setup, reference):
+    """``Engine(device=)`` commits params and the serving state to that
+    device, and the placed engine serves the same greedy tokens."""
+    cfg, params = setup
+    dev = jax.devices()[-1]
+    eng = _engine(cfg, params, device=dev)
+    seen = set()
+
+    def hook(ctx):
+        for leaf in jax.tree.leaves(ctx.state):
+            assert leaf.committed
+            seen.update(leaf.devices())
+
+    fins = eng.serve(_reqs(cfg), on_iteration=hook)
+    _assert_bit_exact(fins, reference)
+    for leaf in jax.tree.leaves(eng.params):
+        assert leaf.committed and leaf.devices() == {dev}
+    assert seen == {dev}

@@ -476,6 +476,7 @@ def _flash_gqa(q, cache, scale, block_s, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rep, dv), q.dtype),
         interpret=interpret,
+        name="flash_decode_attention",
     )(*prefetch, q.reshape(b, g, rep, dk), hk, hv, ck, cv)
     return out.reshape(b, h, dv)
 
@@ -570,6 +571,8 @@ def _flash_gqa_fused(q, cache, k_new, v_new, active, scale, theta, ring,
             jax.ShapeDtypeStruct((b, 1, g * dk), k_new.dtype),
         ],
         interpret=interpret,
+        name=("flash_decode_attention_ring" if ring
+              else "flash_decode_attention"),
     )(
         *prefetch, q.reshape(b, g, rep, dk),
         hk, hv, ck, cv, k_new.reshape(b, 1, g * dk),
@@ -619,6 +622,7 @@ def _flash_latent(q, cache, value_dim, scale, block_s, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
         interpret=interpret,
+        name="flash_decode_attention_latent",
     )(cache.lengths.astype(jnp.int32), q, hk, ck)
 
 

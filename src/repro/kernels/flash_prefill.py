@@ -416,6 +416,7 @@ def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="flash_prefill_attention",
     )(*prefetch, qt, hk, hv, ck, cv, kn, vn)
 
     o = outs[0].reshape(b, g, cq, rep, dv)[:, :, :c]

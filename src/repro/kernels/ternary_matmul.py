@@ -148,6 +148,7 @@ def ternary_matmul_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="ternary_matmul_pallas",
     )(xq, packed)
 
 
@@ -229,6 +230,7 @@ def ternary_matmul_fused_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
+        name="ternary_matmul_fused_pallas",
     )(xq, packed, x_scale.astype(jnp.float32), col_scale.astype(jnp.float32))
 
 
@@ -313,6 +315,7 @@ def ternary_matmul_fused_batched_pallas(
         out_shape=jax.ShapeDtypeStruct((b, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
+        name="ternary_matmul_fused_batched_pallas",
     )(xq, packed, x_scale.astype(jnp.float32), col_scale.astype(jnp.float32))
 
 
@@ -452,6 +455,7 @@ def ternary_matmul_actq_pallas(
             pltpu.VMEM((block_m, block_n), jnp.int32),
         ],
         interpret=interpret,
+        name="ternary_matmul_actq_pallas",
     )(x, packed, col_scale.astype(jnp.float32))
 
 

@@ -159,26 +159,27 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
 
 def _project_qkv(p, x, cfg: ModelConfig, mode: str):
     h, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    hidden = rms_norm(x, p["ln"], cfg.norm_eps)
-    if "wqkv" in p:
-        # fused packed fast path (models/pack.py::fuse_packed): one
-        # act-quant + one kernel launch produce q‖k‖v; the v-adapter
-        # applies to its segment after the split.
-        q, k, v = qops.fused_linear(
-            p["wqkv"], hidden, cfg,
-            out_shapes=((h, hd), (g, hd), (g, hd)),
-            lora_leaves={2: p.get("lora_v")},
-        )
-    else:
-        q = qops.linear(p["wq"], hidden, cfg, mode, out_shape=(h, hd))
-        k = qops.linear(p["wk"], hidden, cfg, mode, out_shape=(g, hd))
-        v = qops.linear(
-            p["wv"], hidden, cfg, mode, out_shape=(g, hd), lora_leaf=p.get("lora_v")
-        )
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    return q, k, v
+    with jax.named_scope("qkv_proj"):
+        hidden = rms_norm(x, p["ln"], cfg.norm_eps)
+        if "wqkv" in p:
+            # fused packed fast path (models/pack.py::fuse_packed): one
+            # act-quant + one kernel launch produce q‖k‖v; the v-adapter
+            # applies to its segment after the split.
+            q, k, v = qops.fused_linear(
+                p["wqkv"], hidden, cfg,
+                out_shapes=((h, hd), (g, hd), (g, hd)),
+                lora_leaves={2: p.get("lora_v")},
+            )
+        else:
+            q = qops.linear(p["wq"], hidden, cfg, mode, out_shape=(h, hd))
+            k = qops.linear(p["wk"], hidden, cfg, mode, out_shape=(g, hd))
+            v = qops.linear(
+                p["wv"], hidden, cfg, mode, out_shape=(g, hd), lora_leaf=p.get("lora_v")
+            )
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        return q, k, v
 
 
 def attention_full(
@@ -240,27 +241,30 @@ def attention_prefill(
     impl = impl or qops.resolve_impl(cfg)
     swa = cfg.attn_type == "swa"
     window = cfg.swa_window if swa else 0
-    if impl == "pallas":
-        o, k_c, v_c = fprefill.flash_prefill_attention(
-            q, k, v, None,
-            window=window, rope_theta=cfg.rope_theta, emit_kv=True,
-            kv_dtype=cache.hot_k.dtype, impl="pallas",
-        )
-        o = o.reshape(b, s, h * hd)
-    else:
-        positions = jnp.arange(s, dtype=jnp.int32)[None]
-        qr = apply_rope(q, positions, cfg.rope_theta)
-        kr = apply_rope(k, positions, cfg.rope_theta)
-        rep = h // g
-        qg = jnp.moveaxis(qr.reshape(b, s, g, rep, hd), 1, 3)
-        o = blockwise_attention(
-            qg, jnp.moveaxis(kr, 1, 2), jnp.moveaxis(v, 1, 2),
-            causal=True, window=window,
-        )
-        o = jnp.moveaxis(o, 3, 1).reshape(b, s, h * hd)
-        k_c, v_c = kr, v
-    cache = kvc.fill_fresh(cache, k_c, v_c, ring=swa)
-    y = qops.linear(p["wo"], o, cfg, mode, lora_leaf=p.get("lora_o"))
+    with jax.named_scope("attention"):
+        if impl == "pallas":
+            o, k_c, v_c = fprefill.flash_prefill_attention(
+                q, k, v, None,
+                window=window, rope_theta=cfg.rope_theta, emit_kv=True,
+                kv_dtype=cache.hot_k.dtype, impl="pallas",
+            )
+            o = o.reshape(b, s, h * hd)
+        else:
+            positions = jnp.arange(s, dtype=jnp.int32)[None]
+            qr = apply_rope(q, positions, cfg.rope_theta)
+            kr = apply_rope(k, positions, cfg.rope_theta)
+            rep = h // g
+            qg = jnp.moveaxis(qr.reshape(b, s, g, rep, hd), 1, 3)
+            o = blockwise_attention(
+                qg, jnp.moveaxis(kr, 1, 2), jnp.moveaxis(v, 1, 2),
+                causal=True, window=window,
+            )
+            o = jnp.moveaxis(o, 3, 1).reshape(b, s, h * hd)
+            k_c, v_c = kr, v
+    with jax.named_scope("kv_write"):
+        cache = kvc.fill_fresh(cache, k_c, v_c, ring=swa)
+    with jax.named_scope("o_proj"):
+        y = qops.linear(p["wo"], o, cfg, mode, lora_leaf=p.get("lora_o"))
     return y, cache
 
 
@@ -299,27 +303,31 @@ def attention_prefill_chunk(
     impl = impl or qops.resolve_impl(cfg)
     swa = cfg.attn_type == "swa"
     window = cfg.swa_window if swa else 0
-    if impl == "pallas":
-        o, k_c, v_c = fprefill.flash_prefill_attention(
-            q, k, v, cache, valid=n_valid,
-            window=window, ring=swa, rope_theta=cfg.rope_theta,
-            emit_kv=True, impl="pallas",
-        )
-    else:
-        positions = cache.lengths.astype(jnp.int32)[:, None] + jnp.arange(
-            c, dtype=jnp.int32
-        )[None]
-        qr = apply_rope(q, positions, cfg.rope_theta)
-        kr = apply_rope(k, positions, cfg.rope_theta)
-        o = kvc.tiered_chunk_attention(
-            qr, kr, v, cache, n_valid, window=window, ring=swa
-        )
-        k_c, v_c = kr, v
+    with jax.named_scope("attention"):
+        if impl == "pallas":
+            o, k_c, v_c = fprefill.flash_prefill_attention(
+                q, k, v, cache, valid=n_valid,
+                window=window, ring=swa, rope_theta=cfg.rope_theta,
+                emit_kv=True, impl="pallas",
+            )
+        else:
+            positions = cache.lengths.astype(jnp.int32)[:, None] + jnp.arange(
+                c, dtype=jnp.int32
+            )[None]
+            qr = apply_rope(q, positions, cfg.rope_theta)
+            kr = apply_rope(k, positions, cfg.rope_theta)
+            o = kvc.tiered_chunk_attention(
+                qr, kr, v, cache, n_valid, window=window, ring=swa
+            )
+            k_c, v_c = kr, v
     if append:
-        cache = kvc.append(cache, k_c, v_c, valid=n_valid, ring=swa)
-    y = qops.linear(
-        p["wo"], o.reshape(b, c, h * hd), cfg, mode, lora_leaf=p.get("lora_o")
-    )
+        with jax.named_scope("kv_write"):
+            cache = kvc.append(cache, k_c, v_c, valid=n_valid, ring=swa)
+    with jax.named_scope("o_proj"):
+        y = qops.linear(
+            p["wo"], o.reshape(b, c, h * hd), cfg, mode,
+            lora_leaf=p.get("lora_o"),
+        )
     return y, (cache if append else (k_c, v_c))
 
 
@@ -350,29 +358,30 @@ def attention_decode(
     q, k, v = _project_qkv(p, x[:, None, :], cfg, mode)  # (b,1,h,hd)
     impl = qops.resolve_impl(cfg)
     swa = cfg.attn_type == "swa"
+    app = kvc.append_decode_ring if swa else kvc.append_decode
+    entry = fd.flash_decode_attention_ring if swa else fd.flash_decode_attention
     if impl == "pallas":
-        entry = fd.flash_decode_attention_ring if swa else fd.flash_decode_attention
-        o, k_rot = entry(
-            q[:, 0], cache, impl=impl,
-            k_new=k[:, 0], v_new=v[:, 0], active=active,
-            rope_theta=cfg.rope_theta,
-        )
-        app = kvc.append_decode_ring if swa else kvc.append_decode
-        cache = app(cache, k_rot, v[:, 0], active=active)
+        with jax.named_scope("attention"):
+            o, k_rot = entry(
+                q[:, 0], cache, impl=impl,
+                k_new=k[:, 0], v_new=v[:, 0], active=active,
+                rope_theta=cfg.rope_theta,
+            )
+        with jax.named_scope("kv_write"):
+            cache = app(cache, k_rot, v[:, 0], active=active)
     else:
-        pos = cache.lengths[:, None]  # (b, 1) per-slot absolute position
-        q = apply_rope(q, pos, cfg.rope_theta)[:, 0]  # (b,h,hd)
-        k = apply_rope(k, pos, cfg.rope_theta)[:, 0]  # (b,g,hd)
-        v = v[:, 0]
-        if swa:
-            cache = kvc.append_decode_ring(cache, k, v, active=active)
-            o = fd.flash_decode_attention_ring(q, cache, impl=impl)
-        else:
-            cache = kvc.append_decode(cache, k, v, active=active)
-            o = fd.flash_decode_attention(q, cache, impl=impl)
-    y = qops.linear(
-        p["wo"], o.reshape(b, h * hd), cfg, mode, lora_leaf=p.get("lora_o")
-    )
+        with jax.named_scope("attention"):
+            pos = cache.lengths[:, None]  # (b, 1) per-slot absolute position
+            q = apply_rope(q, pos, cfg.rope_theta)[:, 0]  # (b,h,hd)
+            k = apply_rope(k, pos, cfg.rope_theta)[:, 0]  # (b,g,hd)
+        with jax.named_scope("kv_write"):
+            cache = app(cache, k, v[:, 0], active=active)
+        with jax.named_scope("attention"):
+            o = entry(q, cache, impl=impl)
+    with jax.named_scope("o_proj"):
+        y = qops.linear(
+            p["wo"], o.reshape(b, h * hd), cfg, mode, lora_leaf=p.get("lora_o")
+        )
     return y, cache
 
 
@@ -558,8 +567,9 @@ def mla_decode(p, x, cfg: ModelConfig, mode, cache: kvc.TieredKVCache,
     q_nope, q_rope = _mla_queries(p, dq, cfg, mode, pos)  # (b,1,h,·)
     c_kv, k_rope = _mla_latent(p, dkv, cfg, pos)
     lat_new = jnp.concatenate([c_kv, k_rope], axis=-1)[:, 0]  # (b, dl+dr)
-    cache = kvc.append_decode(cache, lat_new, jnp.zeros((b, 0), lat_new.dtype),
-                              active=active)
+    with jax.named_scope("kv_write"):
+        cache = kvc.append_decode(
+            cache, lat_new, jnp.zeros((b, 0), lat_new.dtype), active=active)
 
     # absorb W_uk into the query: q_abs = q_nope @ W_uk^T  (per head)
     w_uk = p["w_uk"]["w"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
@@ -586,13 +596,16 @@ def mla_decode(p, x, cfg: ModelConfig, mode, cache: kvc.TieredKVCache,
         att_cache = cache
 
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    ctx = fd.flash_decode_attention_latent(
-        q_full, att_cache, value_dim=m.kv_lora_rank, scale=scale,
-        impl=qops.resolve_impl(cfg),
-    )  # (b,h,dl)
+    with jax.named_scope("attention"):
+        ctx = fd.flash_decode_attention_latent(
+            q_full, att_cache, value_dim=m.kv_lora_rank, scale=scale,
+            impl=qops.resolve_impl(cfg),
+        )  # (b,h,dl)
 
     w_uv = p["w_uv"]["w"].reshape(m.kv_lora_rank, h, m.v_head_dim)
     w_uv_q = weight_quant_ste(w_uv) if cfg.bitnet.enabled and mode != "none" else w_uv
     o = jnp.einsum("bhl,lhv->bhv", ctx, w_uv_q).reshape(b, h * m.v_head_dim)
-    y = qops.linear(p["wo"], o.astype(x.dtype), cfg, mode, lora_leaf=p.get("lora_o"))
+    with jax.named_scope("o_proj"):
+        y = qops.linear(
+            p["wo"], o.astype(x.dtype), cfg, mode, lora_leaf=p.get("lora_o"))
     return y, cache

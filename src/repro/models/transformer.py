@@ -131,16 +131,17 @@ def _embed_tokens(params, cfg: ModelConfig, tokens: jax.Array, dtype) -> jax.Arr
     from repro.core.bitlinear import Int8Linear
 
     emb = params["embed"]
-    if isinstance(emb, Int8Linear):  # int8 rows + per-row scale
-        x = (
-            jnp.take(emb.q, tokens, axis=0).astype(jnp.float32)
-            * jnp.take(emb.scale, tokens, axis=0)
-        ).astype(dtype)
-    else:
-        x = jnp.take(emb["w"], tokens, axis=0).astype(dtype)
-    if cfg.scale_embed:
-        x = x * jnp.asarray(cfg.d_model**0.5, dtype)
-    return x
+    with jax.named_scope("embed"):
+        if isinstance(emb, Int8Linear):  # int8 rows + per-row scale
+            x = (
+                jnp.take(emb.q, tokens, axis=0).astype(jnp.float32)
+                * jnp.take(emb.scale, tokens, axis=0)
+            ).astype(dtype)
+        else:
+            x = jnp.take(emb["w"], tokens, axis=0).astype(dtype)
+        if cfg.scale_embed:
+            x = x * jnp.asarray(cfg.d_model**0.5, dtype)
+        return x
 
 
 def _frontend_embed(params, cfg: ModelConfig, feats: jax.Array, mode: str) -> jax.Array:
@@ -532,10 +533,11 @@ def _attn_block_prefill(bp, x, cfg, mode, cache_layer, n_valid=None):
     else:
         y, cache_layer = attn.attention_prefill(bp["attn"], x, cfg, mode, cache_layer)
     x = x + y
-    if "moe" in bp:
-        h, _ = moe_lib.apply_moe(bp["moe"], x, cfg, mode)
-    else:
-        h = apply_mlp(bp["mlp"], x, cfg, mode)
+    with jax.named_scope("mlp"):
+        if "moe" in bp:
+            h, _ = moe_lib.apply_moe(bp["moe"], x, cfg, mode)
+        else:
+            h = apply_mlp(bp["mlp"], x, cfg, mode)
     return x + h, cache_layer
 
 
@@ -550,16 +552,18 @@ def _prefill_scan(params, cfg, x, cache, mode, n_valid=None):
 
         return jax.lax.scan(step, x1, (stack_params, cache_stack))
 
-    if cfg.family in ("dense", "vlm"):
-        x, cache["attn"] = scan_attn(x, params["blocks"], cache["attn"])
-    elif cfg.family == "moe":
-        if "attn_dense" in cache:
-            x, cache["attn_dense"] = scan_attn(
-                x, params["dense_blocks"], cache["attn_dense"]
-            )
-        x, cache["attn_moe"] = scan_attn(x, params["moe_blocks"], cache["attn_moe"])
-    else:  # pragma: no cover — guarded by _flash_prefill_capable / engine
-        raise ValueError(cfg.family)
+    with jax.named_scope("layers"):
+        if cfg.family in ("dense", "vlm"):
+            x, cache["attn"] = scan_attn(x, params["blocks"], cache["attn"])
+        elif cfg.family == "moe":
+            if "attn_dense" in cache:
+                x, cache["attn_dense"] = scan_attn(
+                    x, params["dense_blocks"], cache["attn_dense"]
+                )
+            x, cache["attn_moe"] = scan_attn(
+                x, params["moe_blocks"], cache["attn_moe"])
+        else:  # pragma: no cover — guarded by _flash_prefill_capable / engine
+            raise ValueError(cfg.family)
     return x, cache
 
 
@@ -599,11 +603,12 @@ def prefill_chunk_step(
     dtype = params["final_ln"].dtype
     x = _embed_tokens(params, cfg, tokens, dtype)  # (slots, C, d)
     x, cache = _prefill_scan(params, cfg, x, cache, mode, n_valid=n_valid)
-    # logits at each slot's last valid row (garbage for idle slots)
-    idx = jnp.clip(n_valid.astype(jnp.int32) - 1, 0, tokens.shape[1] - 1)
-    x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    x_last = rms_norm(x_last, params["final_ln"], cfg.norm_eps)
-    return _lm_logits(params, cfg, x_last), cache
+    with jax.named_scope("lm_head"):
+        # logits at each slot's last valid row (garbage for idle slots)
+        idx = jnp.clip(n_valid.astype(jnp.int32) - 1, 0, tokens.shape[1] - 1)
+        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        x_last = rms_norm(x_last, params["final_ln"], cfg.norm_eps)
+        return _lm_logits(params, cfg, x_last), cache
 
 
 def _spec_scan(params, cfg, x, cache, mode, n_valid):
@@ -697,11 +702,12 @@ def _attn_block_decode(bp, x1, cfg, mode, cache_layer, active=None):
     f = attn.mla_decode if cfg.attn_type == "mla" else attn.attention_decode
     y, cache_layer = f(bp["attn"], x1, cfg, mode, cache_layer, active=active)
     x1 = x1 + y
-    if "moe" in bp:
-        h, _ = moe_lib.apply_moe(bp["moe"], x1[:, None, :], cfg, mode)
-        h = h[:, 0]
-    else:
-        h = apply_mlp(bp["mlp"], x1[:, None, :], cfg, mode)[:, 0]
+    with jax.named_scope("mlp"):
+        if "moe" in bp:
+            h, _ = moe_lib.apply_moe(bp["moe"], x1[:, None, :], cfg, mode)
+            h = h[:, 0]
+        else:
+            h = apply_mlp(bp["mlp"], x1[:, None, :], cfg, mode)[:, 0]
     return x1 + h, cache_layer
 
 
@@ -745,47 +751,49 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array, cache,
 
         return jax.lax.scan(step, x1, (stack_params, state_stack))
 
-    if cfg.family in ("dense", "vlm"):
-        x, cache["attn"] = scan_attn(x, params["blocks"], cache["attn"])
-    elif cfg.family == "moe":
-        if "attn_dense" in cache:
-            x, cache["attn_dense"] = scan_attn(
-                x, params["dense_blocks"], cache["attn_dense"]
-            )
-        x, cache["attn_moe"] = scan_attn(x, params["moe_blocks"], cache["attn_moe"])
-    elif cfg.family == "ssm":
-        x, cache["ssm"] = scan_ssm(x, params["blocks"], cache["ssm"])
-    elif cfg.family == "hybrid":
+    with jax.named_scope("layers"):
+        if cfg.family in ("dense", "vlm"):
+            x, cache["attn"] = scan_attn(x, params["blocks"], cache["attn"])
+        elif cfg.family == "moe":
+            if "attn_dense" in cache:
+                x, cache["attn_dense"] = scan_attn(
+                    x, params["dense_blocks"], cache["attn_dense"]
+                )
+            x, cache["attn_moe"] = scan_attn(x, params["moe_blocks"], cache["attn_moe"])
+        elif cfg.family == "ssm":
+            x, cache["ssm"] = scan_ssm(x, params["blocks"], cache["ssm"])
+        elif cfg.family == "hybrid":
 
-        def group_step(h, xs):
-            gp, gstate, acache, lora_v = xs
-            h, gstate2 = scan_ssm(h, gp, gstate)
-            sp = {"attn": params["shared"]["attn"], "mlp": params["shared"]["mlp"]}
-            if lora_v is not None:
-                sp = {"attn": {**sp["attn"], "lora_v": lora_v}, "mlp": sp["mlp"]}
-            h, acache2 = _attn_block_decode(sp, h, cfg, mode, acache, active)
-            return h, (gstate2, acache2)
+            def group_step(h, xs):
+                gp, gstate, acache, lora_v = xs
+                h, gstate2 = scan_ssm(h, gp, gstate)
+                sp = {"attn": params["shared"]["attn"], "mlp": params["shared"]["mlp"]}
+                if lora_v is not None:
+                    sp = {"attn": {**sp["attn"], "lora_v": lora_v}, "mlp": sp["mlp"]}
+                h, acache2 = _attn_block_decode(sp, h, cfg, mode, acache, active)
+                return h, (gstate2, acache2)
 
-        lora_stack = params.get("shared_lora_v")
-        if lora_stack is None:
-            def step(h, xs_i):
-                gp, gstate, acache = xs_i
-                return group_step(h, (gp, gstate, acache, None))
-            x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
-                step, x, (params["mamba_groups"], cache["mamba"], cache["attn"])
-            )
-        else:
-            def step(h, xs_i):
-                gp, gstate, acache, lv = xs_i
-                return group_step(h, (gp, gstate, acache, lv))
-            x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
-                step, x, (params["mamba_groups"], cache["mamba"], cache["attn"], lora_stack)
-            )
-        if "tail" in cache:
-            x, cache["tail"] = scan_ssm(x, params["mamba_tail"], cache["tail"])
-    else:  # pragma: no cover
-        raise ValueError(cfg.family)
+            lora_stack = params.get("shared_lora_v")
+            if lora_stack is None:
+                def step(h, xs_i):
+                    gp, gstate, acache = xs_i
+                    return group_step(h, (gp, gstate, acache, None))
+                x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
+                    step, x, (params["mamba_groups"], cache["mamba"], cache["attn"])
+                )
+            else:
+                def step(h, xs_i):
+                    gp, gstate, acache, lv = xs_i
+                    return group_step(h, (gp, gstate, acache, lv))
+                x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
+                    step, x, (params["mamba_groups"], cache["mamba"], cache["attn"], lora_stack)
+                )
+            if "tail" in cache:
+                x, cache["tail"] = scan_ssm(x, params["mamba_tail"], cache["tail"])
+        else:  # pragma: no cover
+            raise ValueError(cfg.family)
 
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    logits = _lm_logits(params, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
+        logits = _lm_logits(params, cfg, x)
     return logits, cache

@@ -110,6 +110,7 @@ the decode loop runs on.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -227,6 +228,21 @@ class ServeStats:
     pages_scrubbed: int = 0
     weight_reloads: int = 0
     slots_quarantined: int = 0
+    # observability (docs/serving.md, "Observability"): host seconds per
+    # run_iteration phase (the spans ``engine.<phase>``), and the work
+    # dispatched, counted from the host mirrors with no device read —
+    # decode dispatches, decoding slots summed over them, each decoding
+    # slot's valid prefix (new token included) summed over them, chunk
+    # dispatches and the prompt tokens they carried; a speculative
+    # engine counts its rounds. ``traces`` counts each trace of a jitted
+    # program by name, so a retrace in steady state shows.
+    host_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    decode_dispatches: int = 0
+    decode_slot_steps: int = 0
+    kv_tokens_attended: int = 0
+    chunk_dispatches: int = 0
+    prefill_tokens: int = 0
+    traces: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def record_spec(self, fin: FinishedRequest) -> None:
         self.drafted_tokens += fin.drafted_tokens
@@ -286,6 +302,17 @@ class _ServeCtx:
     page_crc: Dict[int, tuple] = dataclasses.field(default_factory=dict)
     verified_len: Optional[List[int]] = None
     last_scrub: int = -1
+
+
+@contextlib.contextmanager
+def _phase(stats: ServeStats, name: str, **args):
+    """One phase of ``Engine.run_iteration``: a profiler span
+    ``engine.<name>`` on the device trace's clock (free while no trace
+    runs), and its host seconds added to ``stats.host_s``."""
+    t = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"engine.{name}", **args):
+        yield
+    stats.host_s[name] = stats.host_s.get(name, 0.0) + time.perf_counter() - t
 
 
 class Engine:
@@ -480,17 +507,28 @@ class Engine:
         self._set_table_fn = None  # jitted page-table install (growth)
         self._spec_step_fns: dict = {}  # (out_cap, stop) -> jitted round
         self._draft_chunk_fn = None  # jitted draft-cache prefill chunk
+        # the stats that trace-time counts go to: those of the session
+        # whose iteration ran last (run_iteration sets it)
+        self._iter_stats: Optional[ServeStats] = None
+
+        def prefill(p, batch):
+            self._traced("prefill")
+            return T.prefill(p, self.cfg, batch, hot_cap=self.hot_cap,
+                             max_len=self.max_len, mode=self.mode)
+
         # jitted prefill (one compile per admitted (group, prompt) shape)
-        self._prefill = jax.jit(
-            lambda p, batch: T.prefill(
-                p, self.cfg, batch,
-                hot_cap=self.hot_cap, max_len=self.max_len, mode=self.mode,
-            )
-        )
+        self._prefill = jax.jit(prefill)
 
     def _place(self, tree):
         """Commit ``tree`` to this engine's device (no-op without one)."""
         return tree if self.device is None else jax.device_put(tree, self.device)
+
+    def _traced(self, name: str) -> None:
+        """Called at the top of each jitted program's body, so it runs only
+        while JAX traces it: counts the trace into ``ServeStats.traces``."""
+        if self._iter_stats is not None:
+            traces = self._iter_stats.traces
+            traces[name] = traces.get(name, 0) + 1
 
     def _chunked_capable(self) -> bool:
         """Chunked prefill needs a pure attention-token path: per-slot
@@ -619,11 +657,12 @@ class Engine:
         return jax.tree.map(scatter, live, fresh, axes)
 
     def _sample_fn(self, logits: jax.Array, key: jax.Array) -> jax.Array:
-        if self.sample == "greedy":
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            key, logits / self.temperature, axis=-1
-        ).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if self.sample == "greedy":
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.random.categorical(
+                key, logits / self.temperature, axis=-1
+            ).astype(jnp.int32)
 
     # ------------------------------------------------------------------
     # the fully-jitted decode step
@@ -638,35 +677,40 @@ class Engine:
         cfg, mode, hot_cap = self.cfg, self.mode, self.hot_cap
 
         def step(params, state: DecodeState) -> DecodeState:
-            active = state.allocated & ~state.done
-            act32 = active.astype(jnp.int32)
-            # emit the pending token (sampled last step / at admission)
-            emit = (
-                jnp.arange(out_cap, dtype=jnp.int32)[None] == state.n_gen[:, None]
-            ) & active[:, None]
-            out = jnp.where(emit, state.tok[:, None], state.out)
-            n_gen = state.n_gen + act32
+            self._traced("step")
+            with jax.named_scope("bookkeeping"):
+                active = state.allocated & ~state.done
+                act32 = active.astype(jnp.int32)
+                # emit the pending token (sampled last step / at admission)
+                emit = (
+                    jnp.arange(out_cap, dtype=jnp.int32)[None]
+                    == state.n_gen[:, None]
+                ) & active[:, None]
+                out = jnp.where(emit, state.tok[:, None], state.out)
+                n_gen = state.n_gen + act32
+                key_next, sub = jax.random.split(state.key)
             # decode: append the pending token's KV, get next logits
             logits, cache = T.decode_step(
                 params, cfg, state.tok, state.cache, mode=mode, active=active
             )
-            # vectorized per-slot DR ledger at the pre-append length
-            tr = kv_cache.step_traffic_tokens(state.seq_len, hot_cap)
-            ledger = {
-                k: state.ledger[k] + tr[k] * act32 for k in TRAFFIC_KEYS
-            }
-            seq_len = state.seq_len + act32
             # on-device sampling
-            key_next, sub = jax.random.split(state.key)
-            tok = jnp.where(active, self._sample_fn(logits, sub), state.tok)
-            # on-device stop handling: retire via mask, never break the loop
-            done = state.done | (active & (n_gen >= state.max_new))
-            if stop_token is not None:
-                done = done | (active & (tok == stop_token))
-            # SDC sentinel: latch non-finite logits per active slot, on
-            # device — the scrub reads it at the next sync point
-            numerics_bad = state.numerics_bad | (
-                active & ~jnp.isfinite(logits).all(axis=-1))
+            sampled = self._sample_fn(logits, sub)
+            with jax.named_scope("bookkeeping"):
+                # vectorized per-slot DR ledger at the pre-append length
+                tr = kv_cache.step_traffic_tokens(state.seq_len, hot_cap)
+                ledger = {
+                    k: state.ledger[k] + tr[k] * act32 for k in TRAFFIC_KEYS
+                }
+                seq_len = state.seq_len + act32
+                tok = jnp.where(active, sampled, state.tok)
+                # on-device stop handling: retire via mask, never break
+                done = state.done | (active & (n_gen >= state.max_new))
+                if stop_token is not None:
+                    done = done | (active & (tok == stop_token))
+                # SDC sentinel: latch non-finite logits per active slot,
+                # on device — the scrub reads it at the next sync point
+                numerics_bad = state.numerics_bad | (
+                    active & ~jnp.isfinite(logits).all(axis=-1))
             return DecodeState(
                 cache=cache, tok=tok, key=key_next, allocated=state.allocated,
                 done=done, seq_len=seq_len, n_gen=n_gen,
@@ -692,6 +736,7 @@ class Engine:
             return self._admit_fn
 
         def admit(state, fresh, logits, idx, p_len, max_new, key):
+            self._traced("admit")
             first = self._sample_fn(logits, key)
             cache = self._scatter_cache(state.cache, fresh, idx)
             n = idx.shape[0]
@@ -739,39 +784,43 @@ class Engine:
 
         def chunk_step(params, state: DecodeState, tokens, n_valid,
                        is_first, is_last, max_new, key) -> DecodeState:
-            # a slot's first chunk starts from a clean cache row
-            cache = {
-                k: c._replace(
-                    lengths=jnp.where(is_first[None, :], 0, c.lengths)
-                )
-                for k, c in state.cache.items()
-            }
+            self._traced("chunk_step")
+            with jax.named_scope("bookkeeping"):
+                # a slot's first chunk starts from a clean cache row
+                cache = {
+                    k: c._replace(
+                        lengths=jnp.where(is_first[None, :], 0, c.lengths)
+                    )
+                    for k, c in state.cache.items()
+                }
             logits, cache = T.prefill_chunk_step(
                 params, cfg, tokens, cache, n_valid, mode=mode
             )
             first_tok = self._sample_fn(logits, key)
-            z32 = jnp.zeros_like(state.n_gen)
-            done = jnp.where(is_first, False, state.done)
-            ledger = {
-                k: jnp.where(is_first, z32, state.ledger[k])
-                for k in TRAFFIC_KEYS
-            }
-            return DecodeState(
-                cache=cache,
-                tok=jnp.where(is_last, first_tok, state.tok),
-                key=state.key,
-                allocated=state.allocated | is_last,
-                done=jnp.where(is_last, max_new <= 0, done),
-                seq_len=jnp.where(is_first, 0, state.seq_len) + n_valid,
-                n_gen=jnp.where(is_first, 0, state.n_gen),
-                max_new=jnp.where(is_last, max_new, state.max_new),
-                out=jnp.where(is_first[:, None], 0, state.out),
-                ledger=ledger,
-                draft_cache=state.draft_cache,
-                drafted=jnp.where(is_first, 0, state.drafted),
-                accepted=jnp.where(is_first, 0, state.accepted),
-                numerics_bad=jnp.where(is_first, False, state.numerics_bad),
-            )
+            with jax.named_scope("bookkeeping"):
+                z32 = jnp.zeros_like(state.n_gen)
+                done = jnp.where(is_first, False, state.done)
+                ledger = {
+                    k: jnp.where(is_first, z32, state.ledger[k])
+                    for k in TRAFFIC_KEYS
+                }
+                return DecodeState(
+                    cache=cache,
+                    tok=jnp.where(is_last, first_tok, state.tok),
+                    key=state.key,
+                    allocated=state.allocated | is_last,
+                    done=jnp.where(is_last, max_new <= 0, done),
+                    seq_len=jnp.where(is_first, 0, state.seq_len) + n_valid,
+                    n_gen=jnp.where(is_first, 0, state.n_gen),
+                    max_new=jnp.where(is_last, max_new, state.max_new),
+                    out=jnp.where(is_first[:, None], 0, state.out),
+                    ledger=ledger,
+                    draft_cache=state.draft_cache,
+                    drafted=jnp.where(is_first, 0, state.drafted),
+                    accepted=jnp.where(is_first, 0, state.accepted),
+                    numerics_bad=jnp.where(is_first, False,
+                                           state.numerics_bad),
+                )
 
         self._chunk_step_fn = jax.jit(chunk_step, donate_argnums=(1,))
         return self._chunk_step_fn
@@ -792,6 +841,7 @@ class Engine:
 
         def dchunk(dparams, state: DecodeState, tokens, n_valid,
                    is_first) -> DecodeState:
+            self._traced("draft_chunk")
             dcache = {
                 k: c._replace(
                     lengths=jnp.where(is_first[None, :], 0, c.lengths)
@@ -825,6 +875,7 @@ class Engine:
         force_reject = self.spec_force == "reject"
 
         def spec_step(params, dparams, state: DecodeState) -> DecodeState:
+            self._traced("spec_step")
             active = state.allocated & ~state.done
             act32 = active.astype(jnp.int32)
             seq0 = state.seq_len
@@ -936,6 +987,7 @@ class Engine:
 
         def admit(state: DecodeState, reset, new_len, new_table,
                   hot_src, cow_src, cow_dst) -> DecodeState:
+            self._traced("paged_admit")
             vm = jax.vmap(
                 kv_cache.paged_admit,
                 in_axes=(0, None, None, None, None, None, None),
@@ -976,6 +1028,7 @@ class Engine:
             return self._save_hot_fn
 
         def sh(state: DecodeState, slot, page_ids) -> DecodeState:
+            self._traced("save_hot")
             vm = jax.vmap(kv_cache.save_hot, in_axes=(0, None, None))
             cache = {k: vm(c, slot, page_ids) for k, c in state.cache.items()}
             return state._replace(cache=cache)
@@ -993,6 +1046,7 @@ class Engine:
             return self._set_table_fn
 
         def st(state: DecodeState, table) -> DecodeState:
+            self._traced("set_table")
             cache = {
                 k: c._replace(
                     page_table=jnp.broadcast_to(
@@ -1331,13 +1385,18 @@ class Engine:
             n_preemptions=req.n_preemptions,
             drafted_tokens=drafted + req.carry_drafted,
             accepted_tokens=accepted + req.carry_accepted,
+            t_admit=req.t_admit,
+            t_first=req.t_first,
+            t_finish=self._clock(),
         )
 
     def _finish_queued(self, req: Request, outcome: str) -> FinishedRequest:
         """Terminal record for a request that never held a slot at the
         end (rejected / cancelled / expired while queued) — shared with
         the router via ``scheduler.terminal_record``."""
-        return terminal_record(req, outcome)
+        fin = terminal_record(req, outcome)
+        fin.t_finish = self._clock()
+        return fin
 
     def _cancel_slot(self, ctx: _ServeCtx, s: int, outcome: str) -> None:
         """Terminate an active slot mid-flight (cancel / deadline):
@@ -1581,7 +1640,7 @@ class Engine:
         return box[0]
 
     def _stream_chunks(self, state: DecodeState, n_slots: int,
-                       prefilling: Dict[int, list],
+                       prefilling: Dict[int, list], stats: ServeStats,
                        max_waves: Optional[int] = None,
                        on_last=None,
                        draft_prefilling: Optional[Dict[int, list]] = None,
@@ -1655,6 +1714,8 @@ class Engine:
                 for s in d_done:
                     dp.pop(s)
             if any_target:
+                stats.chunk_dispatches += 1
+                stats.prefill_tokens += int(n_valid.sum())
                 self.key, sub = jax.random.split(self.key)
                 state = step(
                     self.params, state, jnp.asarray(toks),
@@ -1827,17 +1888,13 @@ class Engine:
         self._validate_request(req, len(ctx.sched.slot_req))
         return ctx.sched.submit(req)
 
-    def run_iteration(self, ctx: _ServeCtx) -> bool:
-        """One serving-loop iteration: sweep cancellations/expiries,
-        admit into free slots, fund page growth, run one decode chunk,
-        harvest finished slots, fire the hook, count the stall guard.
-        Returns True when the iteration made progress. Call only while
-        ``not ctx.sched.idle()``."""
-        t0 = time.perf_counter()
-        sched, chunk, step = ctx.sched, ctx.chunk, ctx.step_fn
-        n_slots = len(sched.slot_req)
-        progress = self._sweep_cancel_expire(ctx) > 0
-        # -- admission: fill every free slot we can ----------------
+    def _admit_round(self, ctx: _ServeCtx, chunk: int) -> bool:
+        """Admission phase of ``run_iteration``: fill every free slot we
+        can — chunked (stream at most ``chunk`` prompt-chunk waves) or as
+        whole same-length groups. Returns True when anything was
+        admitted or prefilled."""
+        sched = ctx.sched
+        progress = False
         if ctx.chunked:
             fills = sched.next_fills()
             for s, req in fills:
@@ -1863,7 +1920,7 @@ class Engine:
                         ctx.draft_prefilling[s] = [req, 0]
             progress |= bool(ctx.prefilling) or bool(ctx.draft_prefilling)
             ctx.state = self._stream_chunks(
-                ctx.state, n_slots, ctx.prefilling,
+                ctx.state, len(sched.slot_req), ctx.prefilling, ctx.stats,
                 max_waves=chunk, on_last=on_last,
                 draft_prefilling=(ctx.draft_prefilling
                                   if self.spec else None),
@@ -1878,13 +1935,113 @@ class Engine:
                     ctx.remaining[s] = req.max_new_tokens
                     ctx.seq_mirror[s] = self._attempt_prompt_len(req)
                 progress = True
+        return progress
+
+    def _advance_mirrors(self, ctx: _ServeCtx, decoding: List[int],
+                         n_steps: int) -> None:
+        """Advance the host budget/length mirrors of the ``decoding``
+        slots past ``n_steps`` decode dispatches."""
+        if not (self.spec and n_steps):
+            for s in decoding:
+                ctx.remaining[s] = max(ctx.remaining[s] - n_steps, 0)
+                ctx.seq_mirror[s] = min(
+                    ctx.seq_mirror[s] + n_steps, self.max_len)
+            return
+        # a speculative round emits a data-dependent 1..K tokens, so the
+        # deterministic host mirrors no longer hold — refresh them from
+        # the device at the sync point (the harvest reads `done` anyway),
+        # then return the pages the rollback stranded past each slot's
+        # real length so pool occupancy tracks acceptance, not the
+        # funded worst case
+        n_gen_dev = np.asarray(ctx.state.n_gen)
+        seq_dev = np.asarray(ctx.state.seq_len)
+        for s in decoding:
+            req = ctx.sched.slot_req[s]
+            if req is None:
+                continue
+            ctx.remaining[s] = max(
+                int(req.max_new_tokens) - int(n_gen_dev[s]), 0)
+            ctx.seq_mirror[s] = int(seq_dev[s])
+            if not self.paged or not ctx.slot_pages[s]:
+                continue
+            keep = pages_needed(
+                ctx.seq_mirror[s], self.hot_cap, self._page_size)
+            extra = ctx.slot_pages[s][keep:]
+            if extra:
+                ctx.pool.decref(extra)
+                del ctx.slot_pages[s][keep:]
+                # unused table entries must hold a VALID page index
+                # (PagedKVCache convention); the device copy may keep
+                # stale entries — safe, because any row a future round
+                # writes there is re-funded and re-installed by
+                # _ensure_pages first
+                ctx.host_table[s, keep:] = 0
+
+    def _harvest(self, ctx: _ServeCtx, ripe: List[int]) -> None:
+        """Retire the ``ripe`` (done) slots: read their outputs and
+        ledgers, record their terminal records, free their pages."""
+        n_gen = np.asarray(ctx.state.n_gen)
+        seq_len = np.asarray(ctx.state.seq_len)
+        out = np.asarray(ctx.state.out)
+        ledger = {k: np.asarray(ctx.state.ledger[k]) for k in TRAFFIC_KEYS}
+        drafted_dev = np.asarray(ctx.state.drafted) if self.spec else None
+        accepted_dev = np.asarray(ctx.state.accepted) if self.spec else None
+        for s in ripe:
+            req = ctx.sched.retire(s)
+            spec_kw = (
+                dict(drafted=int(drafted_dev[s]),
+                     accepted=int(accepted_dev[s]))
+                if self.spec else {}
+            )
+            fin = self._build_finished(
+                req, out[s, : n_gen[s]].copy(), int(seq_len[s]),
+                {k: ledger[k][s] for k in TRAFFIC_KEYS},
+                self._attempt_prompt_len(req), ctx.prefix_used[s],
+                "finished", ctx.token_bytes, **spec_kw,
+            )
+            ctx.finished.append(fin)
+            ctx.stats.record_spec(fin)
+            self._cancel_requested.discard(req.rid)
+            ctx.prefix_used[s] = 0
+            ctx.remaining[s] = 0
+            ctx.seq_mirror[s] = 0
+            if self.paged:
+                # pages free exactly when their last reader leaves
+                ctx.pool.decref(ctx.slot_pages[s])
+                ctx.slot_pages[s] = []
+        idx = jnp.asarray(ripe, jnp.int32)
+        ctx.state = ctx.state._replace(
+            allocated=ctx.state.allocated.at[idx].set(False)
+        )
+
+    def run_iteration(self, ctx: _ServeCtx) -> bool:
+        """One serving-loop iteration: sweep cancellations/expiries,
+        admit into free slots, fund page growth, run one decode chunk,
+        harvest finished slots, fire the hook, count the stall guard.
+        Each phase is a span ``engine.<phase>`` and adds its host seconds
+        to ``ctx.stats.host_s`` (``_phase``). Returns True when the
+        iteration made progress. Call only while ``not ctx.sched.idle()``."""
+        t0 = time.perf_counter()
+        sched, chunk, step = ctx.sched, ctx.chunk, ctx.step_fn
+        stats = ctx.stats
+        self._iter_stats = stats
+        with _phase(stats, "sweep"):
+            progress = self._sweep_cancel_expire(ctx) > 0
+        # -- admission: fill every free slot we can ----------------
+        with _phase(stats, "admit"):
+            progress |= self._admit_round(ctx, chunk)
+            now = self._clock()
+            for req in sched.slot_req:
+                if req is not None and req.t_admit is None:
+                    req.t_admit = now
         # -- fund mid-decode cold growth (may preempt) -------------
         if self.paged:
             # a speculative round transiently appends up to K rows
-            # before rollback, so fund the worst-case advance — the
-            # trailing decref below returns what rollback strands
-            self._ensure_pages(
-                ctx, chunk * self.spec_k if self.spec else chunk)
+            # before rollback, so fund the worst-case advance —
+            # _advance_mirrors returns what rollback strands
+            with _phase(stats, "grow"):
+                self._ensure_pages(
+                    ctx, chunk * self.spec_k if self.spec else chunk)
         # -- decode chunk: no host syncs inside --------------------
         # clip the chunk so no dispatch runs past the earliest
         # budget-exhaustion among decoding slots (those steps would be
@@ -1900,94 +2057,42 @@ class Engine:
         budgets = [ctx.remaining[s] for s in decoding
                    if ctx.remaining[s] > 0]
         n_steps = min([chunk] + budgets) if budgets else 0
-        for _ in range(n_steps):
-            ctx.state = (step(self.params, self.draft_params, ctx.state)
-                         if self.spec else step(self.params, ctx.state))
-        if self.spec and n_steps:
-            # a speculative round emits a data-dependent 1..K tokens,
-            # so the deterministic host mirrors no longer hold —
-            # refresh them from the device at the sync point (the
-            # harvest below reads `done` anyway), then return the
-            # pages the rollback stranded past each slot's real
-            # length so pool occupancy tracks acceptance, not the
-            # funded worst case
-            n_gen_dev = np.asarray(ctx.state.n_gen)
-            seq_dev = np.asarray(ctx.state.seq_len)
-            for s in decoding:
-                req = sched.slot_req[s]
-                if req is None:
-                    continue
-                ctx.remaining[s] = max(
-                    int(req.max_new_tokens) - int(n_gen_dev[s]), 0)
-                ctx.seq_mirror[s] = int(seq_dev[s])
-                if not self.paged or not ctx.slot_pages[s]:
-                    continue
-                keep = pages_needed(
-                    ctx.seq_mirror[s], self.hot_cap, self._page_size)
-                extra = ctx.slot_pages[s][keep:]
-                if extra:
-                    ctx.pool.decref(extra)
-                    del ctx.slot_pages[s][keep:]
-                    # unused table entries must hold a VALID page
-                    # index (PagedKVCache convention); the device
-                    # copy may keep stale entries — safe, because
-                    # any row a future round writes there is re-
-                    # funded and re-installed by _ensure_pages first
-                    ctx.host_table[s, keep:] = 0
-        else:
-            for s in decoding:
-                ctx.remaining[s] = max(ctx.remaining[s] - n_steps, 0)
-                ctx.seq_mirror[s] = min(
-                    ctx.seq_mirror[s] + n_steps, self.max_len)
+        stats.decode_dispatches += n_steps
+        for s in decoding:
+            # slot s decodes in the first m dispatches, attending to
+            # seq_mirror[s] + 1 .. seq_mirror[s] + m tokens
+            m = min(ctx.remaining[s], n_steps)
+            stats.decode_slot_steps += m
+            stats.kv_tokens_attended += m * ctx.seq_mirror[s] + m * (m + 1) // 2
+        with _phase(stats, "dispatch", n_steps=n_steps):
+            for _ in range(n_steps):
+                ctx.state = (step(self.params, self.draft_params, ctx.state)
+                             if self.spec else step(self.params, ctx.state))
+            self._advance_mirrors(ctx, decoding, n_steps)
         progress |= n_steps > 0
         # -- SDC scrub: detect -> contain -> repair, BEFORE harvest —
         # a ripe slot forces a scrub, so no request ever retires with
         # an unverified tail (engine._scrub, "harvest gating")
         if self.integrity is not None:
-            self._scrub(ctx)
+            with _phase(stats, "scrub"):
+                self._scrub(ctx)
         # -- sync point: harvest finished slots --------------------
         # (the slot table mirrors `allocated`, so only the small
         # `done` mask crosses the device boundary here)
-        done = np.asarray(ctx.state.done)
-        ripe = [s for s in decoding if done[s]]
-        if ripe:
-            progress = True
-            n_gen = np.asarray(ctx.state.n_gen)
-            seq_len = np.asarray(ctx.state.seq_len)
-            out = np.asarray(ctx.state.out)
-            ledger = {k: np.asarray(ctx.state.ledger[k])
-                      for k in TRAFFIC_KEYS}
-            drafted_dev = (np.asarray(ctx.state.drafted)
-                           if self.spec else None)
-            accepted_dev = (np.asarray(ctx.state.accepted)
-                            if self.spec else None)
-            for s in ripe:
-                req = sched.retire(s)
-                spec_kw = (
-                    dict(drafted=int(drafted_dev[s]),
-                         accepted=int(accepted_dev[s]))
-                    if self.spec else {}
-                )
-                fin = self._build_finished(
-                    req, out[s, : n_gen[s]].copy(), int(seq_len[s]),
-                    {k: ledger[k][s] for k in TRAFFIC_KEYS},
-                    self._attempt_prompt_len(req), ctx.prefix_used[s],
-                    "finished", ctx.token_bytes, **spec_kw,
-                )
-                ctx.finished.append(fin)
-                ctx.stats.record_spec(fin)
-                self._cancel_requested.discard(req.rid)
-                ctx.prefix_used[s] = 0
-                ctx.remaining[s] = 0
-                ctx.seq_mirror[s] = 0
-                if self.paged:
-                    # pages free exactly when their last reader leaves
-                    ctx.pool.decref(ctx.slot_pages[s])
-                    ctx.slot_pages[s] = []
-            idx = jnp.asarray(ripe, jnp.int32)
-            ctx.state = ctx.state._replace(
-                allocated=ctx.state.allocated.at[idx].set(False)
-            )
+        with _phase(stats, "sync"):
+            done = np.asarray(ctx.state.done)
+        with _phase(stats, "harvest"):
+            # every slot past its prefill has its first token by now
+            now = self._clock()
+            for s in sched.active_slots():
+                req = sched.slot_req[s]
+                if (req.t_first is None and s not in ctx.prefilling
+                        and s not in ctx.draft_prefilling):
+                    req.t_first = now
+            ripe = [s for s in decoding if done[s]]
+            if ripe:
+                progress = True
+                self._harvest(ctx, ripe)
         # the hook sees the 0-based index of the iteration that just
         # completed (chaos schedules / tests key off it)
         if ctx.on_iteration is not None:

@@ -105,6 +105,11 @@ class Request:
     # FinishedRequest so acceptance accounting survives eviction
     carry_drafted: int = 0
     carry_accepted: int = 0
+    # engine-clock times of the first admission into a slot and of the
+    # sync point that first saw the request past its prefill (its first
+    # token); an earlier attempt's stamps survive preemption
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
     @property
     def prompt_len(self) -> int:
@@ -165,6 +170,13 @@ class FinishedRequest:
     # non-speculative engines.
     drafted_tokens: int = 0
     accepted_tokens: int = 0
+    # engine-clock timestamps (``Engine(clock=...)``): first admission
+    # into a slot, the sync point that first saw the request past its
+    # prefill (its first token), and the terminal record; None where the
+    # request never got that far
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_finish: Optional[float] = None
 
     @property
     def acceptance_rate(self) -> float:
@@ -205,6 +217,7 @@ def terminal_record(req: Request, outcome: str) -> FinishedRequest:
         seq_len=prompt_len + len(tokens), steps=len(tokens),
         traffic=traffic, prefix_tokens_reused=req.carry_reused,
         outcome=outcome, n_preemptions=req.n_preemptions,
+        t_admit=req.t_admit, t_first=req.t_first,
         drafted_tokens=req.carry_drafted,
         accepted_tokens=req.carry_accepted,
     )

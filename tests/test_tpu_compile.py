@@ -6,7 +6,9 @@ v5e chip and compiles it with the TPU compiler, which ships with jaxlib.
 Nothing runs: these tests catch what the Pallas interpreter accepts and
 Mosaic refuses (vector ops it cannot legalize, tiling, VMEM limits), at
 no chip time. Each asserts the compiled program holds the kernel
-(``tpu_custom_call``).
+(``tpu_custom_call``) and that the kernel call carries its ``name=``,
+the entry point's name, which a profiler trace shows for the kernel
+and the benchmark's readers match.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -24,12 +26,16 @@ import pytest
 from repro.core import kv_cache as kvc
 from repro.core import packing
 from repro.kernels import ops
-from repro.kernels.flash_decode import flash_decode_attention
+from repro.kernels.flash_decode import (
+    flash_decode_attention,
+    flash_decode_attention_latent,
+)
 from repro.kernels.flash_prefill import flash_prefill_attention
 from repro.kernels.ternary_matmul import (
     ternary_matmul_actq_pallas,
     ternary_matmul_fused_batched_pallas,
     ternary_matmul_fused_pallas,
+    ternary_matmul_pallas,
 )
 
 D, H, G, HD, FFN = 2048, 8, 4, 256, 8192  # configs/falcon3_1b.py
@@ -70,6 +76,8 @@ def _on(sharding, tree):
 def _assert_kernel(jitted, *args, **kwargs):
     text = jitted.lower(*args, **kwargs).compile().as_text()
     assert "tpu_custom_call" in text
+    # pallas_call(name=...) puts the name on the kernel's op_name path
+    assert f"/{jitted.__name__}/pallas_call" in text
 
 
 def _padded(m, n, k, codec, kind):
@@ -170,3 +178,41 @@ def test_flash_prefill_continuation_compiles(one_chip, paged):
         cache, _spec(one_chip, (slots,), jnp.int32),
         rope_theta=THETA, impl="pallas", interpret=False,
     )
+
+
+@pytest.mark.parametrize("kernel", ["ternary_matmul", "flash_decode_plain",
+                                    "flash_decode_latent"])
+def test_off_path_kernels_carry_their_names(one_chip, kernel):
+    """The kernels the serving steps do not run at these widths: the
+    int8-in ternary matmul, the pre-rotated flash-decode form and the
+    MLA latent form (DeepSeek-V3 widths: 128 heads, latent 512 + rope
+    64)."""
+    if kernel == "ternary_matmul":
+        (bm, bn, bk), (mp, np_, kp, group) = _padded(DECODE_M, D, FFN,
+                                                     "pack2", "fused")
+        _assert_kernel(
+            ternary_matmul_pallas,
+            _spec(one_chip, (mp, kp), jnp.int8),
+            _spec(one_chip, (kp // group, np_), jnp.uint8),
+            codec="pack2", block_m=bm, block_n=bn, block_k=bk,
+            interpret=False)
+    elif kernel == "flash_decode_plain":
+        slots = 8
+        cache = _cache(one_chip, slots, 32, 2048, jnp.bfloat16, False)
+        _assert_kernel(flash_decode_attention,
+                       _spec(one_chip, (slots, H, HD), jnp.bfloat16), cache,
+                       impl="pallas", interpret=False)
+    else:
+        slots, h, dl, dr = 8, 128, 512, 64
+
+        def make():
+            c = kvc.init_cache(slots, 32, 2048 - 32, (dl + dr,),
+                               jnp.bfloat16)
+            return c._replace(hot_v=jnp.zeros((slots, 32, 0), jnp.bfloat16),
+                              cold_v=jnp.zeros((slots, 2048 - 32, 0),
+                                               jnp.bfloat16))
+
+        _assert_kernel(flash_decode_attention_latent,
+                       _spec(one_chip, (slots, h, dl + dr), jnp.bfloat16),
+                       _on(one_chip, jax.eval_shape(make)), dl,
+                       (192.0) ** -0.5, impl="pallas", interpret=False)

@@ -60,7 +60,10 @@ batching rule on this jax version, so the vmapped path was pinned to XLA).
 the activations arrive pre-quantized (``fuse_act_quant=False`` / a
 ``QuantizedActivation`` producer), experts still run as one launch via
 the batched known-scale kernel instead of falling back to the vmapped
-XLA path.
+XLA path. Both act-quant entry points round block_k up to whole plane
+tiles (``_actq_blocks``): the kernel decodes each packed tile into its g
+K-planes and contracts each against a lane-aligned slice of the x tile
+(kernels/ternary_matmul.py).
 
 ``select_blocks(kind="decode_attn")`` serves a different grid entirely:
 the flash-decode attention kernel (kernels/flash_decode.py) keys its
@@ -425,6 +428,18 @@ def ternary_matmul_abft(
     return y, residual, tol
 
 
+def _actq_blocks(m, n, k, codec, block_m, block_n, block_k, kind):
+    """``_resolve_blocks`` for the act-quant kernel, with block_k rounded
+    up to whole plane tiles: the kernel contracts each of a packed tile's
+    g K-planes against its own (bm, bk/g) slice of x, which has to span
+    whole 128-lane tiles (bk a multiple of 512 for pack2, 640 for
+    pack243). The served blocks already are; zero K padding is exact."""
+    bm, bn, bk = _resolve_blocks(m, n, k, codec, block_m, block_n, block_k,
+                                 kind=kind)
+    group = packing.PACK2_GROUP if codec == "pack2" else packing.PACK243_GROUP
+    return bm, bn, _round_up(bk, group * 128)
+
+
 def _actq_xla(x, packed, col_scale, k, codec, act_bits, out_dtype):
     """Quantize-then-matmul reference path: separate act-quant + dot +
     rescale, numerically identical ops to the fused prologue."""
@@ -474,7 +489,7 @@ def ternary_matmul_actq(
     m = 1
     for d in x.shape[:-1]:
         m *= d
-    bm, bn, bk = _resolve_blocks(
+    bm, bn, bk = _actq_blocks(
         m, n, packed.shape[0] * group, codec, block_m, block_n, block_k,
         kind="actq",
     )
@@ -535,7 +550,7 @@ def ternary_matmul_expert(
         raise ValueError(f"unknown impl {impl!r}")
 
     group = packing.PACK2_GROUP if codec == "pack2" else packing.PACK243_GROUP
-    bm, bn, bk = _resolve_blocks(
+    bm, bn, bk = _actq_blocks(
         c, n, kp * group, codec, block_m, block_n, block_k, kind="expert"
     )
     mp = _round_up(max(c, 1), bm)

@@ -15,6 +15,18 @@ The ternary MAC itself ({-1,0,+1} weights) rides the MXU int8 datapath:
 values -1/0/+1 in int8 make the dot product exactly the add/sub/skip of
 the TriMLA truth table (verified bit-exactly against ref.py).
 
+Packed row r holds K rows g*r .. g*r+g-1, so the shift/mask (pack2) or
+divmod-3 (pack243) decode of a (bk/g, bn) tile yields g *K-planes*, plane
+i holding K row g*r+i. The known-scale kernels interleave the planes back
+into a (bk, bn) tile in K order — a sublane relayout of every weight tile
+on every grid step. The act-quant kernel skips it (*plane decode*): its
+entry point reorders the activations once per call into plane order
+within each K tile, and the kernel contracts plane i against the
+lane-aligned slice i of its x tile, adding g int8 dots into the
+accumulator. The sums are the same integers in another order, so the
+output is bit-identical to a K-order decode; bk/g has to be a multiple
+of 128 lanes (ops.py rounds block_k up to it).
+
 Block shapes default to MXU-aligned (multiples of 128 on M/N, K tiles
 sized so the packed rows stay lane-aligned). VMEM footprint per step:
   x tile (bm, bk) int8 + packed tile (bk/g, bn) uint8
@@ -64,30 +76,48 @@ from repro.core import packing
 from repro.core.ternary import EPS
 
 
-def _decode2_block(wp: jax.Array) -> jax.Array:
-    """(bk/4, bn) uint8 -> (bk, bn) int8 trits (2-bit codes, LSB=+, MSB=-).
+def _planes2(wp: jax.Array) -> list:
+    """(bk/4, bn) uint8 -> four (bk/4, bn) int8 trit planes (2-bit codes,
+    LSB=+, MSB=-); plane i holds trit i of every byte, i.e. K row 4r+i of
+    packed row r.
 
     The shifts, masks and the subtraction run in int32 and only the trits
     narrow to int8: Mosaic has no 8-bit vector shift or subtract."""
     v = wp.astype(jnp.int32)
-    parts = []
+    planes = []
     for i in range(packing.PACK2_GROUP):
         c = v >> (2 * i)
-        parts.append(((c & 1) - ((c >> 1) & 1)).astype(jnp.int8))
-    stacked = jnp.stack(parts, axis=1)  # (bk/4, 4, bn)
-    return stacked.reshape(stacked.shape[0] * packing.PACK2_GROUP, stacked.shape[2])
+        planes.append(((c & 1) - ((c >> 1) & 1)).astype(jnp.int8))
+    return planes
+
+
+def _planes243(wp: jax.Array) -> list:
+    """(bk/5, bn) uint8 -> five (bk/5, bn) int8 trit planes via repeated
+    divmod-3, in int32 (Mosaic has no 16-bit vector remainder or
+    division); plane i holds K row 5r+i of packed row r."""
+    v = wp.astype(jnp.int32)
+    planes = []
+    for _ in range(packing.PACK243_GROUP):
+        planes.append((v % 3 - 1).astype(jnp.int8))
+        v = v // 3
+    return planes
+
+
+def _interleave(planes: list) -> jax.Array:
+    """g (bk/g, bn) planes -> (bk, bn) trits in K order (row g*r+i from
+    plane i): a sublane relayout of the whole tile."""
+    stacked = jnp.stack(planes, axis=1)  # (bk/g, g, bn)
+    return stacked.reshape(stacked.shape[0] * len(planes), stacked.shape[2])
+
+
+def _decode2_block(wp: jax.Array) -> jax.Array:
+    """(bk/4, bn) uint8 -> (bk, bn) int8 trits in K order."""
+    return _interleave(_planes2(wp))
 
 
 def _decode243_block(wp: jax.Array) -> jax.Array:
-    """(bk/5, bn) uint8 -> (bk, bn) int8 trits via repeated divmod-3,
-    in int32 (Mosaic has no 16-bit vector remainder or division)."""
-    v = wp.astype(jnp.int32)
-    parts = []
-    for _ in range(packing.PACK243_GROUP):
-        parts.append((v % 3 - 1).astype(jnp.int8))
-        v = v // 3
-    stacked = jnp.stack(parts, axis=1)  # (bk/5, 5, bn)
-    return stacked.reshape(stacked.shape[0] * packing.PACK243_GROUP, stacked.shape[2])
+    """(bk/5, bn) uint8 -> (bk, bn) int8 trits in K order."""
+    return _interleave(_planes243(wp))
 
 
 def _kernel(x_ref, w_ref, o_ref, *, codec: str, k_steps: int):
@@ -335,6 +365,11 @@ def _actq_kernel(x_ref, w_ref, ws_ref, o_ref, scale_ref, acc_ref, *,
     so the int8 activations never exist outside VMEM. Zero-padded rows
     quantize to all-zero int8 rows (absmax 0 -> huge scale ->
     round(0 * scale) = 0), so no separate pad-scale repair is needed.
+
+    The x tile's K axis arrives in plane order (see the entry point): the
+    packed tile decodes into its g K-planes and each contracts against
+    its own lane-aligned (bm, bk/g) slice of x, so the weight tile is
+    never interleaved back into K order.
     """
     j = pl.program_id(2)
     p = pl.program_id(3)
@@ -364,21 +399,42 @@ def _actq_kernel(x_ref, w_ref, ws_ref, o_ref, scale_ref, acc_ref, *,
 
     @pl.when(p == 1)
     def _quantized_accumulate():
-        x = x_ref[0].astype(jnp.float32)
-        xq = jnp.clip(jnp.round(x * scale_ref[...]), qmin, qmax).astype(jnp.int8)
-        decode = _decode2_block if codec == "pack2" else _decode243_block
-        trits = decode(w_ref[0])  # (bk, bn) int8 in {-1,0,+1}
-        acc_ref[...] += jax.lax.dot_general(
-            xq,
-            trits,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        split = _planes2 if codec == "pack2" else _planes243
+        acc = acc_ref[...]
+        for i, plane in enumerate(split(w_ref[0])):  # (bk/g, bn) int8
+            kq = plane.shape[0]
+            x = x_ref[0, :, i * kq:(i + 1) * kq].astype(jnp.float32)
+            xq = jnp.clip(jnp.round(x * scale_ref[...]), qmin, qmax)
+            acc += jax.lax.dot_general(
+                xq.astype(jnp.int8),
+                plane,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+        acc_ref[...] = acc
 
     @pl.when((p == 1) & (kk == k_steps - 1))
     def _epilogue():
         y = acc_ref[...].astype(jnp.float32) * (ws_ref[0] / scale_ref[...])
         o_ref[0] = y.astype(o_ref.dtype)
+
+
+def _plane_order(x: jax.Array, block_k: int, group: int) -> jax.Array:
+    """(B, M, K) -> the same columns in plane order within each block_k
+    tile: ``out[..., t*bk + i*(bk/g) + r] = x[..., t*bk + g*r + i]``, so
+    slice i of a tile meets K-plane i of its packed tile. The absmax, the
+    zero padding and the int32 sums are the same in any K order."""
+    # Without the barrier XLA fuses the reorder into the ops that produce
+    # x (the residual add and the RMSNorm) and the served logits drift
+    # from the K-order decode's (a v5e at M = 1536). One cause is excess
+    # precision in that fusion: it recomputes the residual sum in float32
+    # and skips its bf16 rounding. With --xla_allow_excess_precision=false
+    # that rounding returns but the drift does not go away. The barrier
+    # keeps the producers compiled as they are without the reorder.
+    x = jax.lax.optimization_barrier(x)
+    b, m, k = x.shape
+    tiles = x.reshape(b, m, k // block_k, block_k // group, group)
+    return jnp.swapaxes(tiles, -1, -2).reshape(b, m, k)
 
 
 @functools.partial(
@@ -407,9 +463,12 @@ def ternary_matmul_actq_pallas(
     weight scale. B = 1 for ordinary projections; B = E runs the E-loop
     expert grid (one launch over all experts, each with its own packed
     weights and column scales).
+
+    block_k / g must be a multiple of 128, so that each K-plane's slice of
+    the x tile is whole lane tiles (``_plane_order``).
     """
     group = packing.PACK2_GROUP if codec == "pack2" else packing.PACK243_GROUP
-    assert block_k % group == 0, (block_k, group)
+    assert block_k % (group * 128) == 0, (block_k, group)
     b, m, k = x.shape
     bb, kp, n = packed.shape
     assert bb == b and kp * group == k, (bb, b, kp, group, k)
@@ -422,6 +481,7 @@ def ternary_matmul_actq_pallas(
     else:  # mirror act_quant so pallas and xla reject identically
         raise ValueError(f"unsupported activation bits: {act_bits}")
 
+    x = _plane_order(x, block_k, group)
     grid = (b, m // block_m, n // block_n, 2, k // block_k)
     return pl.pallas_call(
         functools.partial(_actq_kernel, codec=codec, k_steps=grid[4],

@@ -10,6 +10,8 @@ Covers the production path end to end (ISSUE 2 + ISSUE 3):
     the carried-scale (fuse_act_quant=False) form, which no longer falls
     back to the vmapped XLA path;
   * MLA down-projection fusion (w_dq‖w_dkv -> "w_dqkv", post-split norms);
+  * plane decode of the act-quant kernel (K-planes against plane-ordered
+    activations) bit-identical to the K-order decode and to XLA;
   * shape-aware block selection (decode-shaped auto blocks stay exact);
   * pack2/pack243 zero-code padding repair regression (operator precedence);
   * fuse_packed / FusedPackedLinear: fused QKV and gate-up vs separate
@@ -397,6 +399,69 @@ def test_mla_fused_down_projection_exact():
     y_f = attn.mla_full(pf, x, cfg, "packed", pos)
     y_u = attn.mla_full(pu, x, cfg, "packed", pos)
     np.testing.assert_array_equal(np.asarray(y_f), np.asarray(y_u))
+
+
+# ---------------------------------------------------------------------------
+# Plane decode of the act-quant kernel
+# ---------------------------------------------------------------------------
+
+PLANE_K = {"pack2": 1024, "pack243": 1280}  # two plane tiles each
+PLANE_CASES = {  # case -> (M, K short of PLANE_K); "expert" is the E-loop form
+    "m12": (12, 0), "m32": (32, 0), "m256": (256, 0), "zero_row": (12, 0),
+    "pad_k": (12, 24), "expert": (5, 0), "k64": (12, None),
+}
+
+
+@pytest.mark.parametrize("codec", CODECS)
+@pytest.mark.parametrize("case", list(PLANE_CASES))
+def test_actq_plane_decode_bit_identical(codec, case):
+    """The act-quant kernel contracts each K-plane of a packed tile against
+    its slice of the plane-ordered activations; the result is
+    bit-identical to the XLA quantize-then-matmul path and to the
+    known-scale kernel, which decodes the trits in K order. K = 64 pads
+    up to one whole plane tile."""
+    from repro.core.ternary import act_quant
+
+    m, k_cut = PLANE_CASES[case]
+    k, n = (64 if k_cut is None else PLANE_K[codec] - k_cut), 512
+    if case == "expert":
+        x, packed = _expert_case(k + m, 2, m, k, n, codec)
+        cs = jax.random.uniform(jax.random.PRNGKey(5), (2, n)) + 0.5
+        fn, k_order = ops.ternary_matmul_expert, ops.ternary_matmul_expert_fused
+    else:
+        x, packed = _raw_case(k + m, m, k, n, codec)
+        cs = jax.random.uniform(jax.random.PRNGKey(5), (n,)) + 0.5
+        fn, k_order = ops.ternary_matmul_actq, ops.ternary_matmul_fused
+    x = x.astype(jnp.bfloat16)
+    if case == "zero_row":
+        x = x.at[3].set(0.0)
+
+    got = fn(x, packed, cs, k=k, codec=codec, impl="pallas")
+    want = fn(x, packed, cs, k=k, codec=codec, impl="xla")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    q = act_quant(x)
+    want = k_order(q.xq, packed, q.scale, cs, k=k, codec=codec, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if case == "zero_row":
+        np.testing.assert_array_equal(np.asarray(got[3]), 0.0)
+
+
+def test_actq_blocks_whole_plane_tiles():
+    """The act-quant entry points round block_k up to whole plane tiles
+    (block_k / g a multiple of 128 lanes), and the served decode and
+    prefill blocks are already whole, so the tables' blocks stand."""
+    def bk(m, k, codec, block_k=None, kind="actq"):
+        return ops._actq_blocks(m, 512, k, codec, None, None, block_k, kind)[2]
+
+    assert bk(12, 64, "pack2") == 512 and bk(12, 65, "pack243") == 640
+    assert bk(12, 1024, "pack2", block_k=256) == 512
+    assert bk(12, 1280, "pack243", block_k=320, kind="expert") == 640
+    for k in (2048, 3072, 8192, 23040):
+        for m in (32, 256):
+            for codec in CODECS:
+                for kind in ("actq", "expert"):
+                    args = (m, 4096, k, codec, None, None, None, kind)
+                    assert ops._actq_blocks(*args) == ops._resolve_blocks(*args)
 
 
 # ---------------------------------------------------------------------------

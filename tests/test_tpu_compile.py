@@ -80,19 +80,32 @@ def _assert_kernel(jitted, *args, **kwargs):
     assert f"/{jitted.__name__}/pallas_call" in text
 
 
-def _padded(m, n, k, codec, kind):
-    """Block sizes and padded operand extents as ops.py would pick them."""
+def _padded(m, n, k, codec, kind, resolve=ops._resolve_blocks):
+    """Block sizes and padded operand extents as ops.py would pick them
+    (``resolve=ops._actq_blocks`` for the act-quant kernel)."""
     group = packing.PACK2_GROUP if codec == "pack2" else packing.PACK243_GROUP
-    bm, bn, bk = ops._resolve_blocks(m, n, k, codec, None, None, None, kind)
+    bm, bn, bk = resolve(m, n, k, codec, None, None, None, kind)
     return (bm, bn, bk), (ops._round_up(m, bm), ops._round_up(n, bn),
                           ops._round_up(ops._round_up(k, group), bk), group)
 
 
-@pytest.mark.parametrize("m", [DECODE_M, PREFILL_M])
-@pytest.mark.parametrize("codec", ["pack2", "pack243"])
-@pytest.mark.parametrize("k,n", [(D, QKV_N), (FFN, D)], ids=["qkv", "down"])
-def test_actq_matmul_compiles(one_chip, m, codec, k, n):
-    (bm, bn, bk), (mp, np_, kp, group) = _padded(m, n, k, codec, "actq")
+# the served decode shapes of the benchmark's cells, K x N:
+# falcon3-7b (d 3072, FFN 23040) and falcon3-1b fused gate|up
+SERVED = {"7b-gate_up": (3072, 2 * 23040), "7b-down": (23040, 3072),
+          "1b-gate_up": (D, 2 * FFN)}
+ACTQ_CASES = [
+    pytest.param(k, n, codec, m, id=f"{name}-{codec}-{m}")
+    for name, (k, n) in (("qkv", (D, QKV_N)), ("down", (FFN, D)))
+    for codec in ("pack2", "pack243")
+    for m in (DECODE_M, PREFILL_M)
+] + [pytest.param(k, n, "pack2", DECODE_M, id=f"{name}-pack2-{DECODE_M}")
+     for name, (k, n) in SERVED.items()]
+
+
+@pytest.mark.parametrize("k,n,codec,m", ACTQ_CASES)
+def test_actq_matmul_compiles(one_chip, k, n, codec, m):
+    (bm, bn, bk), (mp, np_, kp, group) = _padded(m, n, k, codec, "actq",
+                                                 ops._actq_blocks)
     _assert_kernel(
         ternary_matmul_actq_pallas,
         _spec(one_chip, (1, mp, kp), jnp.bfloat16),
@@ -122,8 +135,9 @@ def test_fused_matmul_compiles(one_chip, m):
                          ids=["actq", "carried-scale"])
 def test_expert_matmul_compiles(one_chip, carried_scale):
     e, c = 8, DECODE_M
-    (bm, bn, bk), (mp, np_, kp, group) = _padded(c, 2 * FFN // e, D, "pack2",
-                                                 "expert")
+    (bm, bn, bk), (mp, np_, kp, group) = _padded(
+        c, 2 * FFN // e, D, "pack2", "expert",
+        ops._resolve_blocks if carried_scale else ops._actq_blocks)
     w = _spec(one_chip, (e, kp // group, np_), jnp.uint8)
     ws = _spec(one_chip, (e, 1, np_), jnp.float32)
     blocks = dict(codec="pack2", block_m=bm, block_n=bn, block_k=bk,

@@ -12,7 +12,7 @@ least time of its calls over their summed device time.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, List, Sequence, Tuple
+from typing import Iterable, Sequence, Tuple
 
 ACT_BYTES = 2  # bf16 activations into the ternary matmul
 OUT_BYTES = 4  # the packed matmul returns float32
@@ -20,30 +20,14 @@ OUT_BYTES = 4  # the packed matmul returns float32
 
 @dataclasses.dataclass(frozen=True)
 class Projection:
-    """One stacked packed projection as served: ``layers`` x (K, N)."""
+    """One stacked packed projection as served: ``layers`` x (K, N). A
+    model module (``bench/models/``) reads them from the served tree."""
 
     name: str
     layers: int
     k: int
     n: int
     weight_bytes: int  # packed words and scales of one layer
-
-
-def projections(params) -> List[Projection]:
-    """Every packed projection leaf of the served tree (fused or not)."""
-    out = []
-    for block_name, block in sorted(params["blocks"].items()):
-        for name, leaf in sorted(block.items()):
-            packed = getattr(leaf, "packed", None)
-            if packed is None:
-                continue
-            layers = packed.shape[0]
-            scale = leaf.scale
-            per_layer = (packed.size * packed.dtype.itemsize
-                         + scale.size * scale.dtype.itemsize) // layers
-            out.append(Projection(f"{block_name}.{name}", layers, int(leaf.k),
-                                  int(packed.shape[-1]), int(per_layer)))
-    return out
 
 
 def ternary_call(m: int, p: Projection) -> Tuple[float, float]:
@@ -54,6 +38,8 @@ def ternary_call(m: int, p: Projection) -> Tuple[float, float]:
 
 
 def ternary_ops_per_token(projs: Iterable[Projection]) -> float:
+    """Operations of one token through every layer of ``projs``: the
+    projections that each token runs, as its model module counts them."""
     return sum(2.0 * p.k * p.n * p.layers for p in projs)
 
 

@@ -17,12 +17,13 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from bench.lib import check, client, costs, model, trace, traffic
+from bench.lib import check, client, trace, traffic
 from bench.lib.peaks import peaks
 from bench.lib.spec import Spec
 
@@ -37,16 +38,19 @@ class NoChip(RuntimeError):
 
 @dataclasses.dataclass
 class Run:
-    """What the metric readers see of one run."""
+    """What the metric readers see of one run: with its model module
+    (``bench/models/``), that module's sizes and the shapes and dtypes of
+    the parameter tree the engine served (``ShapeDtypeStruct`` leaves)."""
 
     cell: str
     records: List[client.Record]
     window: dict
     setup_s: float
+    model: ModuleType
     sizes: dict
+    served: Any
     slots: int
     chunk: int
-    projections: List[costs.Projection]
     kv_itemsize: int
     trace: Optional[trace.Trace] = None
     calls: Optional[Dict[str, list]] = None
@@ -198,8 +202,9 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     """One run of ``workload``; returns the result line as a dict.
 
     ``control`` switches on one of the program's lower-precision paths
-    (``model.CONTROLS``): the check's control. ``fault(engine, session)``
-    breaks the timed path before the window (the check's fault tests).
+    (``bench.lib.model.CONTROLS``): the check's control.
+    ``fault(engine, session)`` breaks the timed path before the window
+    (the check's fault tests).
     Neither is used by the benchmark's own runs.
     """
     t_setup0 = time.perf_counter() - process_seconds()
@@ -211,6 +216,7 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     conf = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
     eng_conf = mix["engine"]
+    model = spec.model(conf)
     sizes = model.sizes(conf)
     cfg = model.model_config(conf, control)
     device = devs[0]
@@ -261,10 +267,11 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     peak = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
     sess.read_in_flight()
     records = [r for r in sess.records.values() if r.due <= window["close"]]
+    served = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          engine.params)
     run = Run(cell=workload, records=records, window=window, setup_s=setup_s,
-              sizes=sizes, slots=eng_conf["slots"],
-              chunk=eng_conf["prefill_chunk"],
-              projections=costs.projections(engine.params),
+              model=model, sizes=sizes, served=served,
+              slots=eng_conf["slots"], chunk=eng_conf["prefill_chunk"],
               kv_itemsize=1 if cfg.bitnet.kv_fp8 else 2, calls=calls)
     print(f"window: {window['close'] - window['open']:.3f} s, requests "
           f"{len(records)}, preemptions {sess.ctx.stats.preemptions}, pool "

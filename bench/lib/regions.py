@@ -12,8 +12,11 @@ each device operation's event metadata. ``read`` wire-decodes the file
 with ``google.protobuf`` against the few fields of the XPlane schema it
 needs, on the same clock as ``trace.reduce``.
 
-An operation belongs to the innermost leaf region on its op_name path;
-an operation on no leaf region's path (the layer scan's own slicing and
+The leaf regions are the common ones (``COMMON_REGIONS``: the engine's,
+the KV cache's and attention's, which every model has) and those that the
+run's model module adds (its ``LEAF_REGIONS``, ``bench/models/``). An
+operation belongs to the innermost leaf region on its op_name path; an
+operation on no leaf region's path (the layer scan's own slicing and
 restacking, copies XLA inserts with empty metadata) is ``unattributed``.
 Times are in seconds.
 """
@@ -29,8 +32,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from bench.lib import trace
 from bench.lib.spec import ROOT
 
-LEAF_REGIONS = ("embed", "qkv_proj", "kv_write", "attention", "o_proj",
-                "mlp", "lm_head", "sample", "bookkeeping")
+COMMON_REGIONS = ("embed", "kv_write", "attention", "lm_head", "sample",
+                  "bookkeeping")
 UNATTRIBUTED = "unattributed"
 PHASE_PREFIX = "engine."
 # phases in which the host feeds or waits for the device: the rest is
@@ -52,10 +55,11 @@ class Scoped:
                 if n.startswith(PHASE_PREFIX)]
 
 
-def region(op_name: str) -> str:
-    """The innermost leaf region on an op_name path."""
+def region(op_name: str, leaves: Sequence[str] = ()) -> str:
+    """The innermost leaf region on an op_name path: one of
+    ``COMMON_REGIONS`` or of the model's ``leaves``."""
     for part in reversed(op_name.split("/")):
-        if part in LEAF_REGIONS:
+        if part in COMMON_REGIONS or part in leaves:
             return part
     return UNATTRIBUTED
 
@@ -113,10 +117,12 @@ def _times(line, ev) -> Tuple[float, float]:
     return start_ns * 1e-9, (start_ns + ev.duration_ps // 1000) * 1e-9
 
 
-def read(path: Path, span_names: Sequence[str] = ()) -> Scoped:
-    """Device operations of chip 0 with their regions, program
-    executions, and the host spans ``engine.*`` plus ``span_names``,
-    inside the traced window (``trace.WINDOW_SPAN``)."""
+def read(path: Path, span_names: Sequence[str] = (),
+         leaves: Sequence[str] = ()) -> Scoped:
+    """Device operations of chip 0 with their regions (the common ones
+    and the model's ``leaves``), program executions, and the host spans
+    ``engine.*`` plus ``span_names``, inside the traced window
+    (``trace.WINDOW_SPAN``)."""
     space = _space_class()()
     space.ParseFromString(Path(path).read_bytes())
     window = None
@@ -137,7 +143,7 @@ def read(path: Path, span_names: Sequence[str] = ()) -> Scoped:
                     if st.metadata_id == tf_op:
                         path_ = (st.str_value if st.str_value
                                  else stat_names.get(st.ref_value, ""))
-                regions[key] = region(path_.rsplit(":", 1)[0])
+                regions[key] = region(path_.rsplit(":", 1)[0], leaves)
             for line in plane.lines:
                 if line.name == trace.OPS_LINE:
                     for ev in line.events:
@@ -193,7 +199,7 @@ def step_split(sc: Scoped, program: str = "step") -> Optional[Dict[str, float]]:
             continue
         by[reg] = by.get(reg, 0.0) + (b - a)
         inside[i].append((a, b))
-    if not set(by) & set(LEAF_REGIONS):
+    if set(by) <= {UNATTRIBUTED}:
         return None
     n = len(runs)
     step_s = sum(b - a for a, b in runs)
@@ -260,24 +266,26 @@ def gaps_by_phase(sc: Scoped, n: int = 10) -> List[list]:
 
 
 @functools.lru_cache(maxsize=1)
-def _read_run_file(path: str, mtime_ns: int) -> Scoped:
+def _read_run_file(path: str, mtime_ns: int,
+                   leaves: Tuple[str, ...]) -> Scoped:
     from bench.lib.harness import SPAN_NAMES
 
-    return read(Path(path), SPAN_NAMES)
+    return read(Path(path), SPAN_NAMES, leaves)
 
 
 def of_run(run) -> Optional[Scoped]:
     """The scoped reading of a traced run of ``bench/run.py``, from the
     trace file its reduction read (found where ``run_cell`` leaves it by
-    default, and matched to the run by the traced window); None where
-    there is none."""
+    default, and matched to the run by the traced window), with the leaf
+    regions of the run's model; None where there is none."""
     if run.trace is None:
         return None
     try:
         path = trace.find(RUN_TRACE_DIR)
     except FileNotFoundError:
         return None
-    sc = _read_run_file(str(path), path.stat().st_mtime_ns)
+    sc = _read_run_file(str(path), path.stat().st_mtime_ns,
+                        tuple(run.model.LEAF_REGIONS))
     if any(abs(x - y) > 1e-6 for x, y in zip(sc.window, run.trace.window)):
         return None  # a trace left by another run
     return sc

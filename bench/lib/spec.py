@@ -4,19 +4,26 @@ A cell (``workloads`` entry) names a configuration and a traffic mix; the
 configuration's file is in its ``configs`` entry, the mix is
 ``bench/traffic/<traffic>.json``, the cell's correctness limits are
 ``bench/cells/<cell>.json`` and each metric's reader is
-``bench/metrics/<metric>.py``. Adding a configuration, a cell or a
-metric adds files and entries and edits none.
+``bench/metrics/<metric>.py``. The configuration file names, beside its
+plain reference (``"reference"``: ``bench/references/<reference>.py``),
+its model module (``"model"``: ``bench/models/<model>.py``), which holds
+all that the benchmark knows of the model's blocks. Adding a
+configuration, a model kind, a cell or a metric adds files and entries
+and edits none.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, List
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
+MODELS = BENCH / "models"
 
 
 class Spec:
@@ -58,10 +65,29 @@ class Spec:
 
     def reader(self, metric: str) -> Callable:
         path = BENCH / "metrics" / f"{metric}.py"
-        mod_name = "bench_metric_" + metric.replace(".", "_").replace("-", "_")
-        spec = importlib.util.spec_from_file_location(mod_name, path)
-        if spec is None or not path.exists():
+        if not path.is_file():
             raise FileNotFoundError(f"no reader for metric {metric!r}: {path}")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(path, "bench_metric_" + metric).read
+
+    def model(self, conf: dict) -> ModuleType:
+        """The model module that the configuration file names with
+        ``"model"``, loaded from ``MODELS`` by path (its interface is in
+        ``bench/models/__init__.py``)."""
+        name = conf.get("model")
+        path = MODELS / f"{name}.py"
+        if not name or not path.is_file():
+            raise FileNotFoundError(
+                f"configuration {conf.get('name')!r} names model {name!r}: "
+                f"no module {path}")
+        return _load(path, "bench_model_" + name)
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    mod_name = name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs, as an import would: a dataclass in the
+    # module looks its module up there
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod

@@ -2,7 +2,9 @@
 
 Written from the layer equations, in float32, with no kernel, cache or
 batching of the program, and nothing imported from it. The weights come
-again from the seed (``bench/lib/model.py``), layer by layer.
+again from the seed, layer by layer, from the module that makes the
+program's: ``bench/models/llama_bitnet.py`` (``layer_weights`` and
+``outer_weights``).
 
 Per layer, for hidden states x (S, d):
 
@@ -33,7 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import model as M
+from bench.lib.model import seed_key
+from bench.models import llama_bitnet as M
 
 Q_BLOCK = 512  # query rows per attention block
 ROW_BLOCK = 1024  # rows per MLP block
@@ -137,7 +140,7 @@ def logits(conf: dict, seed: int, sequences, length: int):
     """
     s = M.sizes(conf)
     skey = tuple(sorted(s.items()))
-    key = M.seed_key(seed)
+    key = seed_key(seed)
     pad = -(-length // ROW_BLOCK) * ROW_BLOCK
     with jax.default_matmul_precision("highest"):
         outer = _outer(key, skey)

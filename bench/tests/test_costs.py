@@ -1,6 +1,6 @@
 """The ops and bytes functions against hand counts at falcon3-1b's shapes
-(d 2048, GQA 8/4 x 256, FFN 8192), and the peaks table's refusal of an
-unknown chip.
+(d 2048, GQA 8/4 x 256, FFN 8192), the Llama module's reading of the
+served projections, and the peaks table's refusal of an unknown chip.
 
   JAX_PLATFORMS=cpu python -m pytest bench/tests
 """
@@ -18,6 +18,7 @@ sys.path[:0] = [str(Path(__file__).resolve().parents[2])]
 
 from bench.lib import costs  # noqa: E402
 from bench.lib.peaks import peaks  # noqa: E402
+from bench.models import llama_bitnet  # noqa: E402
 
 WQKV = costs.Projection("attn.wqkv", 18, 2048, 4096,
                         2048 // 4 * 4096 + 4 * 4096)
@@ -34,7 +35,7 @@ def test_projections_read_the_served_leaves():
     leaf = SimpleNamespace(packed=np.zeros((18, 512, 4096), np.uint8),
                            scale=np.zeros((18, 4096), np.float32), k=2048)
     tree = {"blocks": {"attn": {"wqkv": leaf, "ln": np.zeros((18, 2048))}}}
-    assert costs.projections(tree) == [WQKV]
+    assert llama_bitnet.projections(tree) == [WQKV]
     assert costs.ternary_ops_per_token([WQKV]) == 2 * 2048 * 4096 * 18
 
 

@@ -20,26 +20,29 @@ import pytest
 sys.path[:0] = [str(Path(__file__).resolve().parents[2])]
 
 from bench.lib import regions, trace  # noqa: E402
+from bench.models import llama_bitnet  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 METRICS = Path(__file__).resolve().parents[1] / "metrics"
 SPANS = ("submit", "run_iteration", "observe", "wait")
 NEW_METRICS = ("kv_write_ms.tok_s", "unattributed_ms.tok_s",
                "iteration_host_ms.tok_s")
+LEAVES = llama_bitnet.LEAF_REGIONS
 
 
 def test_region_is_the_innermost_leaf():
     assert regions.region(
         "jit(step)/while/body/closed_call/kv_write/dynamic_update_slice"
     ) == "kv_write"
-    assert regions.region(
-        "jit(step)/layers/while/body/closed_call/qkv_proj/"
-        "jit(ternary_matmul_actq)/ternary_matmul_actq_pallas/pallas_call"
-    ) == "qkv_proj"
-    assert regions.region("jit(step)/sample") == "sample"
-    assert regions.region("jit(step)/layers/while/body/dynamic_slice") == (
-        regions.UNATTRIBUTED)
-    assert regions.region("") == regions.UNATTRIBUTED
+    qkv = ("jit(step)/layers/while/body/closed_call/qkv_proj/"
+           "jit(ternary_matmul_actq)/ternary_matmul_actq_pallas/pallas_call")
+    assert regions.region(qkv, LEAVES) == "qkv_proj"
+    # a model's own leaf is a region only for that model
+    assert regions.region(qkv) == regions.UNATTRIBUTED
+    assert regions.region("jit(step)/sample", LEAVES) == "sample"
+    assert regions.region("jit(step)/layers/while/body/dynamic_slice",
+                          LEAVES) == regions.UNATTRIBUTED
+    assert regions.region("", LEAVES) == regions.UNATTRIBUTED
 
 
 def test_nested_gap_labels():
@@ -62,7 +65,7 @@ def test_nested_gap_labels():
 
 @pytest.fixture(scope="module")
 def scoped():
-    return regions.read(DATA / "tiny_scoped.xplane.pb", SPANS)
+    return regions.read(DATA / "tiny_scoped.xplane.pb", SPANS, LEAVES)
 
 
 @pytest.fixture(scope="module")
@@ -110,7 +113,7 @@ def test_phases_and_gaps(scoped, reduced):
 def test_trace_without_scopes_reads_nothing():
     """The trace of a program without regions or phases: the reduction
     calls every operation unattributed and gives no split or phases."""
-    sc = regions.read(DATA / "tiny.xplane.pb", SPANS)
+    sc = regions.read(DATA / "tiny.xplane.pb", SPANS, LEAVES)
     assert {r for *_, r in sc.ops} == {regions.UNATTRIBUTED}
     assert regions.step_split(sc) is None
     assert regions.phase_seconds(sc) is None
@@ -138,10 +141,12 @@ def test_readers(tmp_path, monkeypatch, metric):
         shutil.rmtree(tmp_path, ignore_errors=True)
         dst.parent.mkdir(parents=True)
         shutil.copy(src, dst)
-        run = types.SimpleNamespace(trace=trace.reduce(dst, SPANS))
+        run = types.SimpleNamespace(trace=trace.reduce(dst, SPANS),
+                                    model=llama_bitnet)
         value = read(run)
         assert (value is not None and value > 0) == expect, (name, value)
-        other = types.SimpleNamespace(trace=types.SimpleNamespace(
+        other = types.SimpleNamespace(model=llama_bitnet,
+                                      trace=types.SimpleNamespace(
             window=(run.trace.window[0] + 1.0, run.trace.window[1])))
         assert read(other) is None
     assert read(types.SimpleNamespace(trace=None)) is None

@@ -16,7 +16,8 @@ import pytest
 sys.path[:0] = [str(Path(__file__).resolve().parents[2])]
 
 from bench.lib import trace  # noqa: E402
-from bench.lib.readers import KERNELS, PROGRAMS  # noqa: E402
+from bench.lib.readers import PROGRAMS  # noqa: E402
+from bench.models.llama_bitnet import KERNELS  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 SPANS = ("submit", "run_iteration", "observe", "wait")

@@ -86,7 +86,9 @@ class CompileCounter:
 class Tracer:
     """Starts the profiler a while into the window and stops it at an
     iteration boundary, recording every decode and chunk call made in
-    between (from the session's host-side mirrors, no device read)."""
+    between (from the session's host-side mirrors, no device read). A
+    decode call's lengths are its slots' cache lengths after the append,
+    in every layout."""
 
     def __init__(self, sess: client.Session, seconds: float, out: Path):
         self.sess, self.out = sess, out
@@ -124,7 +126,8 @@ class Tracer:
                               is_last, *rest)
 
         ctx.step_fn = step
-        eng._chunk_step_fn = chunk
+        if real_chunk is not None:  # whole-prompt admission has none
+            eng._chunk_step_fn = chunk
 
     def open(self, t_open: float) -> None:
         self.start_at = t_open + self.lead
@@ -153,18 +156,55 @@ class Tracer:
             self.on, self.done = False, True
 
 
+def layout(mix: dict) -> str:
+    """The engine layout a traffic file asks for: ``paged`` (paged KV
+    pool, chunked prefill), ``contiguous`` (contiguous cache, chunked
+    prefill) or ``whole`` (contiguous cache, whole-prompt admission).
+
+    Whole-prompt admission compiles once per group of equal prompt
+    lengths, so no warm-up covers a mix that admits in the window: a
+    whole-prompt file is taken only as a fixed batch, a closed loop of
+    at most ``slots`` clients whose first wave is admitted in set-up.
+    Raises ``ValueError`` for any other file, before weights are made."""
+    eng = mix["engine"]
+    if eng["paged"]:
+        return "paged"
+    if eng.get("prefix_sharing") or "pool_pages" in eng:
+        raise ValueError("a non-paged traffic file states prefix_sharing "
+                         "false and has no pool_pages")
+    if eng["prefill_chunk"] > 0:
+        return "contiguous"
+    if not (mix["loop"] == "closed" and mix.get("first_wave_in_setup")
+            and mix["clients"] <= eng["slots"]):
+        raise ValueError(
+            "whole-prompt admission (prefill_chunk 0) compiles once per "
+            "prompt length, so its traffic must be a fixed batch: a closed "
+            "loop with first_wave_in_setup and at most `slots` clients")
+    return "whole"
+
+
 def warm(sess: client.Session, eng_conf: dict, vocab: int) -> None:
-    """Run every program the window uses once: a request of three chunk
-    waves whose decode crosses a page boundary (chunk step, paged admit,
-    hot-tier snapshot, decode step, page-table install, harvest), then
-    the harvest scatter at every count of slots that can retire at once."""
+    """Run every program the window uses once, with one request, then the
+    harvest scatter at every count of slots that can retire at once.
+
+    Chunked layouts: a request of three chunk waves whose decode crosses a
+    page boundary (chunk step, paged admit, hot-tier snapshot, decode
+    step, page-table install, harvest); a contiguous cache has no pages,
+    and the chunk stands in for the page in the request's length.
+    Whole-prompt: a request whose decode crosses from the hot tier into
+    the cold (its own prefill and admit, decode step, harvest); the
+    window's batch is admitted in set-up (``layout``)."""
     import jax.numpy as jnp
 
     eng = sess.engine
-    c, hot, ps = eng_conf["prefill_chunk"], eng_conf["hot_cap"], eng._page_size
-    n = hot + ps - 2
-    while n <= 2 * c:
-        n += ps
+    c, hot = eng_conf["prefill_chunk"], eng_conf["hot_cap"]
+    if c:
+        ps = eng._page_size if eng_conf["paged"] else c
+        n = hot + ps - 2
+        while n <= 2 * c:
+            n += ps
+    else:
+        n = max(hot - 2, 1)
     rng = np.random.default_rng(0)
     plan = traffic.Planned(index=-1, max_new=8,
                            prompt=rng.integers(0, vocab, n, dtype=np.int32))
@@ -176,6 +216,19 @@ def warm(sess: client.Session, eng_conf: dict, vocab: int) -> None:
         idx = jnp.asarray(list(range(k)), jnp.int32)  # as the harvest makes it
         alloc.at[idx].set(False).block_until_ready()
     sess.records.clear()
+
+
+def cache_size(engine, eng_conf: dict, pool=None) -> str:
+    """The KV cache for the logs: a paged engine's pool (its pages in use,
+    given the session's ``pool``), else the contiguous cache's tiers."""
+    if eng_conf["paged"]:
+        if pool is not None:
+            return f"pool pages in use {pool.used()}"
+        return (f"pool pages {eng_conf['pool_pages']} of "
+                f"{engine._page_size} tokens")
+    slots, n, hot = eng_conf["slots"], eng_conf["max_len"], eng_conf["hot_cap"]
+    return (f"contiguous cache {slots} slots x {n} tokens (hot {hot}, "
+            f"cold {n - hot})")
 
 
 def process_seconds() -> float:
@@ -216,6 +269,7 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     conf = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
     eng_conf = mix["engine"]
+    paged = layout(mix) == "paged"
     model = spec.model(conf)
     sizes = model.sizes(conf)
     cfg = model.model_config(conf, control)
@@ -228,13 +282,16 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
         cfg, model.program_params(seed, conf), hot_cap=eng_conf["hot_cap"],
         max_len=eng_conf["max_len"], slots=eng_conf["slots"],
         sync_every=eng_conf["sync_every"],
-        prefill_chunk=eng_conf["prefill_chunk"], paged=eng_conf["paged"],
-        n_pages=eng_conf["pool_pages"],
+        prefill_chunk=eng_conf["prefill_chunk"], paged=paged,
+        n_pages=eng_conf["pool_pages"] if paged else None,
         prefix_sharing=eng_conf["prefix_sharing"], seed=0)
     gc.collect()
-    print(f"memory after the model: {device.memory_stats()}; pool pages "
-          f"{eng_conf['pool_pages']} of {engine._page_size} tokens", file=log)
+    print(f"memory after the model: {device.memory_stats()}; "
+          f"{cache_size(engine, eng_conf)}", file=log)
     sess = client.Session(engine, eng_conf)
+    if eng_conf["prefill_chunk"] and not sess.ctx.chunked:
+        raise ValueError(f"{cfg.name} cannot chunk prefill into its cache: "
+                         "its traffic file states prefill_chunk 0")
     compiles = CompileCounter()
     warm(sess, eng_conf, sizes["vocab"])
     mix_plan = traffic.Mix(mix, seed, seconds, sizes["vocab"])
@@ -274,8 +331,8 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
               slots=eng_conf["slots"], chunk=eng_conf["prefill_chunk"],
               kv_itemsize=1 if cfg.bitnet.kv_fp8 else 2, calls=calls)
     print(f"window: {window['close'] - window['open']:.3f} s, requests "
-          f"{len(records)}, preemptions {sess.ctx.stats.preemptions}, pool "
-          f"pages in use {sess.ctx.pool.used()}, compiles in "
+          f"{len(records)}, preemptions {sess.ctx.stats.preemptions}, "
+          f"{cache_size(engine, eng_conf, sess.ctx.pool)}, compiles in "
           f"window {compiles.count}, peak bytes {peak}", file=log)
     # the program's state goes before the trace is read and the
     # reference runs: the device's peak has been taken

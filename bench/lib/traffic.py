@@ -12,6 +12,26 @@ finished, with no think time). A closed loop's first ``clients``
 requests, the batch its window opens with, are a quantile set of their
 own, so every seed starts the same batch; later requests follow in
 streams of ``CLOSED_STREAM``.
+
+A mix's ``engine`` block builds the ``Engine`` in one of three layouts
+(``bench.lib.harness.layout``). Every layout states ``slots``,
+``max_len``, ``hot_cap``, ``sync_every``, ``prefill_chunk``, ``paged``
+and ``prefix_sharing``:
+
+* paged: ``"paged": true`` with ``prefill_chunk`` > 0 and the pool's
+  size, ``pool_pages``; ``prefix_sharing`` as the cell needs. Only a
+  full-attention model can page.
+* contiguous: ``"paged": false``, ``"prefix_sharing": false``,
+  ``prefill_chunk`` > 0, and no ``pool_pages``. Models whose cache
+  chunked prefill can stream (full or windowed attention).
+* whole-prompt: as contiguous, with ``"prefill_chunk": 0``. Any model.
+  Admission compiles once per group of equal prompt lengths, so a
+  whole-prompt mix must be a fixed batch: ``"loop": "closed"`` with
+  ``"first_wave_in_setup": true``, at most ``slots`` clients, and
+  outputs that outlast the window, so that nothing is admitted in it.
+  The harness refuses any other whole-prompt mix before it makes the
+  weights. Each prompt length of the batch compiles its own prefill in
+  set-up: few distinct lengths keep set-up short.
 """
 
 from __future__ import annotations

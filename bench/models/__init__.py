@@ -36,6 +36,12 @@ here, and edits no file of ``bench/lib/``. A module provides:
     needs: for a model with routed experts, the experts each token is
     routed to and the shared ones, never every expert.
 
+The cells of a module whose program cannot page its cache (any attention
+but full: latent, windowed, linear, state-space) name a traffic file with
+``"paged": false``, and with ``"prefill_chunk": 0`` where the program
+cannot chunk prefill into that cache either; ``bench/lib/traffic.py``
+gives each layout's keys.
+
 ``run`` is the :class:`bench.lib.harness.Run` of one traced run: the
 module's ``sizes`` (``run.sizes``), the shapes of the served tree
 (``run.served``), the slots and chunk, the KV item size, the decode and

@@ -12,10 +12,19 @@ The cache is a pytree of fixed-shape arrays (jit/scan friendly):
   cold_k/cold_v : (batch, cold_cap, ...)     the rest
   lengths       : (batch,) int32             tokens written, per slot
 
-``...`` is whatever a layer caches per token: (n_kv_heads, head_dim) for
-GQA/MQA, (d_latent,) for MLA latents. Appends route on position; attention
-runs per-tier and combines with a numerically-stable streaming softmax, so
-no concat of the two tiers is ever materialized.
+``...`` is whatever a layer caches per token, stored flat: (n_kv_heads *
+head_dim,) for GQA/MQA — the flash kernels' (rows, g·hd) operand layout,
+so the decode step hands the stored tiers to the kernel with no relayout
+— and (d_latent,) for MLA latents. Appends take new rows in either form
+((…, g, hd) or (…, g·hd): row-major bytes are the same) and route on
+position; attention runs per-tier and combines with a numerically-stable
+streaming softmax, so no concat of the two tiers is ever materialized.
+
+The model keeps one cache per layer stack, every leaf with a leading
+layer axis. The decode step writes layer ``l``'s new row in place
+(``append_decode(..., layer=l)``) and the flash-decode kernel reads
+layer ``l`` of the stack in place, so no per-layer copy of a tier is
+ever made.
 
 Continuous batching (serving/scheduler.py) treats each batch row as a
 *slot*: sequences of different lengths decode side by side, so every
@@ -42,6 +51,7 @@ here supports paging with bit-exact parity to the contiguous cache.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -183,18 +193,13 @@ def as_tiered(cache: PagedKVCache) -> TieredKVCache:
     return TieredKVCache(cache.hot_k, cache.hot_v, ck, cv, cache.lengths)
 
 
-def _active_mask(cache: TieredKVCache, active: Optional[jax.Array]) -> jax.Array:
-    if active is None:
-        return jnp.ones(cache.lengths.shape, bool)
-    return active.astype(bool)
-
-
 def append(
     cache: TieredKVCache,
     k_new: jax.Array,
     v_new: jax.Array,
     valid: Optional[jax.Array] = None,
     ring: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> TieredKVCache:
     """Append up to ``t_new`` tokens (batch, t_new, ...). Early positions
     land hot.
@@ -211,12 +216,42 @@ def append(
     ring mode only each slot's last ``cold_cap`` valid tokens write (the
     earlier ones would be evicted within this very call; keeping a single
     writer per ring slot keeps the one-hot scatter exact).
+
+    With ``layer`` the cache is layer-stacked and only layer ``layer``'s
+    rows and lengths change, in place (``_append_at``).
     """
-    if isinstance(cache, PagedKVCache):
-        assert not ring, "ring layout is not supported for paged caches"
-        return _paged_append(cache, k_new, v_new, valid)
+    return _on_layer(_append_at, cache, layer, k_new, v_new, valid, ring)
+
+
+def as_stack(cache, layer):
+    """(layer-stacked cache, layer index) for a cache and the layer the
+    caller works on: ``layer`` None means an unstacked cache, lifted to a
+    stack of one (a leading unit axis, no copy) at layer 0."""
+    if layer is None:
+        return jax.tree.map(lambda a: a[None], cache), jnp.zeros((), jnp.int32)
+    return cache, layer
+
+
+def _on_layer(fn, cache, layer, *args):
+    """``fn(stack, layer, *args)`` -> stack, unstacked again when the
+    cache came unstacked (``as_stack``)."""
+    stack, at = as_stack(cache, layer)
+    out = fn(stack, at, *args)
+    return out if layer is not None else jax.tree.map(lambda a: a[0], out)
+
+
+def _append_at(stack, layer, k_new, v_new, valid, ring: bool):
+    """``append`` into layer ``layer`` of a layer-stacked cache. Paged
+    cold rows scatter into the stacked pool at page-table-routed rows
+    (``mode="drop"`` skips the rows a slot does not write; the serving
+    layer gives each writable page one writer slot, so indices never
+    collide). The hot tier and a contiguous cold tier take a one-hot
+    update of the layer's tier, written back in place: a pass over one
+    layer's tier, never a copy of the pool."""
+    k_new = _rows(k_new, 2, stack.hot_k, stacked=True)
+    v_new = _rows(v_new, 2, stack.hot_v, stacked=True)
     t_new = k_new.shape[1]
-    start = cache.lengths  # (b,)
+    start = stack.lengths[layer]  # (b,)
     t_idx = jnp.arange(t_new, dtype=jnp.int32)[None]  # (1, t)
     pos = start[:, None] + t_idx  # (b, t)
     if valid is None:
@@ -226,170 +261,111 @@ def append(
         n_new = valid.astype(jnp.int32)
         vmask = t_idx < n_new[:, None]
 
-    def scatter(tier_k, tier_v, tier_pos, in_tier):
-        # tier_pos: (b, t) position within the tier (clipped); in_tier: bool
-        cap = tier_k.shape[1]
-        if cap == 0:
-            return tier_k, tier_v
-        idx = jnp.clip(tier_pos, 0, cap - 1)
-        onehot = (
-            jax.nn.one_hot(idx, cap, dtype=tier_k.dtype)
-            * in_tier.astype(tier_k.dtype)[..., None]
-        )  # (b, t, cap)
-        upd_k = jnp.einsum("btc,bt...->bc...", onehot, k_new.astype(tier_k.dtype))
-        upd_v = jnp.einsum("btc,bt...->bc...", onehot, v_new.astype(tier_v.dtype))
-        written = jnp.einsum("btc->bc", onehot) > 0
-        mask = written.reshape(written.shape + (1,) * (tier_k.ndim - 2))
-        return jnp.where(mask, upd_k, tier_k), jnp.where(mask, upd_v, tier_v)
-
-    in_hot = (pos < cache.hot_cap) & vmask
-    hot_k, hot_v = scatter(cache.hot_k, cache.hot_v, pos, in_hot)
-    in_cold = (pos >= cache.hot_cap) & vmask
-    cold_pos = pos - cache.hot_cap
-    if ring and cache.cold_cap:
-        cold_pos = cold_pos % cache.cold_cap
-        # single writer per ring slot: only the last cold_cap valid rows
-        in_cold &= (n_new[:, None] - 1 - t_idx) < cache.cold_cap
-    cold_k, cold_v = scatter(cache.cold_k, cache.cold_v, cold_pos, in_cold)
-    return TieredKVCache(hot_k, hot_v, cold_k, cold_v, start + n_new)
-
-
-def _paged_cold_rows(cache: PagedKVCache, cold_pos, write):
-    """Linear row index into the flattened pool for each cold position;
-    entries not selected by ``write`` get an out-of-range index so a
-    ``mode="drop"`` scatter skips them. ``cold_pos``/``write``: (b, ...)
-    with matching shapes; routing is per slot along axis 0."""
-    ps = cache.page_size
-    pg = jnp.clip(cold_pos // ps, 0, cache.pages_per_slot - 1)
-    page = jnp.take_along_axis(
-        cache.page_table, pg.reshape(pg.shape[0], -1), axis=1
-    ).reshape(pg.shape)
-    lin = page * ps + cold_pos % ps
-    return jnp.where(write, lin, cache.n_pages * ps)
-
-
-def _paged_append(
-    cache: PagedKVCache,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    valid: Optional[jax.Array],
-) -> PagedKVCache:
-    """Bulk append for the paged cache: hot rows use the same one-hot
-    scatter as the contiguous path; cold rows scatter into the flattened
-    pool at page-table-routed linear indices. The serving layer guarantees
-    each writable page has exactly one writer slot (shared pages are only
-    ever *read*; see serving/paging.py), so indices never collide."""
-    t_new = k_new.shape[1]
-    start = cache.lengths  # (b,)
-    t_idx = jnp.arange(t_new, dtype=jnp.int32)[None]  # (1, t)
-    pos = start[:, None] + t_idx  # (b, t)
-    if valid is None:
-        vmask = jnp.ones(pos.shape, bool)
-        n_new = jnp.full_like(start, t_new)
-    else:
-        n_new = valid.astype(jnp.int32)
-        vmask = t_idx < n_new[:, None]
-
-    def scatter_hot(tier, new):
-        cap = tier.shape[1]
-        if cap == 0:
-            return tier
-        in_hot = (pos < cap) & vmask
-        idx = jnp.clip(pos, 0, cap - 1)
-        onehot = (jax.nn.one_hot(idx, cap, dtype=tier.dtype)
-                  * in_hot.astype(tier.dtype)[..., None])
-        upd = jnp.einsum("btc,bt...->bc...", onehot, new.astype(tier.dtype))
-        written = jnp.einsum("btc->bc", onehot) > 0
-        mask = written.reshape(written.shape + (1,) * (tier.ndim - 2))
-        return jnp.where(mask, upd, tier)
-
-    hot_k = scatter_hot(cache.hot_k, k_new)
-    hot_v = scatter_hot(cache.hot_v, v_new)
-
-    in_cold = (pos >= cache.hot_cap) & vmask
-    lin = _paged_cold_rows(cache, pos - cache.hot_cap, in_cold).reshape(-1)
-    tail = cache.pool_k.shape[2:]
-    n_rows = cache.n_pages * cache.page_size
-    pk = cache.pool_k.reshape((n_rows,) + tail)
-    pv = cache.pool_v.reshape((n_rows,) + tail)
-    pk = pk.at[lin].set(k_new.astype(pk.dtype).reshape((-1,) + tail),
-                        mode="drop")
-    pv = pv.at[lin].set(v_new.astype(pv.dtype).reshape((-1,) + tail),
-                        mode="drop")
-    return cache._replace(
-        hot_k=hot_k, hot_v=hot_v,
-        pool_k=pk.reshape(cache.pool_k.shape),
-        pool_v=pv.reshape(cache.pool_v.shape),
-        lengths=start + n_new,
-    )
-
-
-def _paged_append_one(
-    cache: PagedKVCache,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    active: Optional[jax.Array],
-) -> PagedKVCache:
-    """Decode append (one token per slot) for the paged cache."""
-    pos = cache.lengths  # (b,)
-    act = _active_mask(cache, active)
-    in_hot = pos < cache.hot_cap
-
-    def upd_hot(tier, new):
-        cap = tier.shape[1]
-        if cap == 0:
-            return tier
-        idx = jnp.clip(pos, 0, cap - 1)
-        onehot = idx[:, None] == jnp.arange(cap, dtype=jnp.int32)[None]
-        mask = onehot & in_hot[:, None] & act[:, None]
-        mask = mask.reshape(mask.shape + (1,) * (tier.ndim - 2))
-        return jnp.where(mask, new.astype(tier.dtype)[:, None], tier)
-
-    lin = _paged_cold_rows(cache, pos - cache.hot_cap, ~in_hot & act)
-    tail = cache.pool_k.shape[2:]
-    n_rows = cache.n_pages * cache.page_size
-    pk = cache.pool_k.reshape((n_rows,) + tail)
-    pv = cache.pool_v.reshape((n_rows,) + tail)
-    pk = pk.at[lin].set(k_new.astype(pk.dtype), mode="drop")
-    pv = pv.at[lin].set(v_new.astype(pv.dtype), mode="drop")
-    return cache._replace(
-        hot_k=upd_hot(cache.hot_k, k_new),
-        hot_v=upd_hot(cache.hot_v, v_new),
-        pool_k=pk.reshape(cache.pool_k.shape),
-        pool_v=pv.reshape(cache.pool_v.shape),
-        lengths=pos + act.astype(jnp.int32),
-    )
-
-
-def _append_one(
-    cache: TieredKVCache,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    active: Optional[jax.Array],
-    ring: bool,
-) -> TieredKVCache:
-    if isinstance(cache, PagedKVCache):
-        assert not ring, "ring layout is not supported for paged caches"
-        return _paged_append_one(cache, k_new, v_new, active)
-    pos = cache.lengths  # (b,)
-    act = _active_mask(cache, active)
-    in_hot = pos < cache.hot_cap
-
-    def upd(tier, new, tier_pos, write):
-        cap = tier.shape[1]
+    def onehot_put(tier, new, tier_pos, write):
+        # tier_pos: (b, t) position within the tier; write: (b, t) bool
+        cap = tier.shape[2]
         if cap == 0:  # zero-size tier (e.g. SWA: hot_cap=0) — nothing to write
             return tier
-        idx = tier_pos % cap if ring else jnp.clip(tier_pos, 0, cap - 1)
-        onehot = idx[:, None] == jnp.arange(cap, dtype=jnp.int32)[None]  # (b, cap)
-        mask = onehot & write[:, None] & act[:, None]
-        mask = mask.reshape(mask.shape + (1,) * (tier.ndim - 2))
-        return jnp.where(mask, new.astype(tier.dtype)[:, None], tier)
+        idx = jnp.clip(tier_pos, 0, cap - 1)
+        onehot = (jax.nn.one_hot(idx, cap, dtype=tier.dtype)
+                  * write.astype(tier.dtype)[..., None])  # (b, t, cap)
+        upd = jnp.einsum("btc,bt...->bc...", onehot, new.astype(tier.dtype))
+        written = jnp.einsum("btc->bc", onehot) > 0
+        mask = written.reshape(written.shape + (1,) * (upd.ndim - 2))
+        return tier.at[layer].set(jnp.where(mask, upd, tier[layer]))
 
-    hot_k = upd(cache.hot_k, k_new, pos, in_hot)
-    hot_v = upd(cache.hot_v, v_new, pos, in_hot)
-    cold_k = upd(cache.cold_k, k_new, pos - cache.hot_cap, ~in_hot)
-    cold_v = upd(cache.cold_v, v_new, pos - cache.hot_cap, ~in_hot)
-    return TieredKVCache(hot_k, hot_v, cold_k, cold_v, pos + act.astype(jnp.int32))
+    hot_cap = stack.hot_k.shape[2]
+    in_hot = (pos < hot_cap) & vmask
+    kw = dict(hot_k=onehot_put(stack.hot_k, k_new, pos, in_hot),
+              hot_v=onehot_put(stack.hot_v, v_new, pos, in_hot))
+    in_cold = (pos >= hot_cap) & vmask
+    cold_pos = pos - hot_cap
+    if isinstance(stack, PagedKVCache):
+        assert not ring, "ring layout is not supported for paged caches"
+        n_pages, ps = stack.pool_k.shape[1:3]
+        pg = jnp.clip(cold_pos // ps, 0, stack.page_table.shape[2] - 1)
+        page = jnp.take_along_axis(stack.page_table[layer], pg, axis=1)
+        page = jnp.where(in_cold, page, n_pages)
+
+        def put_pool(pool, new):
+            return pool.at[layer, page, cold_pos % ps].set(
+                new.astype(pool.dtype), mode="drop")
+
+        kw.update(pool_k=put_pool(stack.pool_k, k_new),
+                  pool_v=put_pool(stack.pool_v, v_new))
+    else:
+        cold_cap = stack.cold_k.shape[2]
+        if ring and cold_cap:
+            cold_pos = cold_pos % cold_cap
+            # single writer per ring slot: only the last cold_cap valid rows
+            in_cold &= (n_new[:, None] - 1 - t_idx) < cold_cap
+        kw.update(cold_k=onehot_put(stack.cold_k, k_new, cold_pos, in_cold),
+                  cold_v=onehot_put(stack.cold_v, v_new, cold_pos, in_cold))
+    kw["lengths"] = stack.lengths.at[layer].set(start + n_new)
+    return stack._replace(**kw)
+
+
+def _rows(new: jax.Array, n_lead: int, tier: jax.Array, stacked: bool = False):
+    """New rows ``(*lead, g, hd)`` or ``(*lead, g·hd)`` in the tier's
+    stored per-token shape (a row-major reshape: the bytes are the same)."""
+    return new.reshape(new.shape[:n_lead] + tier.shape[3 if stacked else 2:])
+
+
+def _append_one_at(stack, layer, k_new, v_new, active, ring: bool):
+    """Decode append into layer ``layer`` of a layer-stacked cache: one
+    row per slot, scattered in place (rows a slot does not write get an
+    out-of-range index, which ``mode="drop"`` skips). The cost is the b
+    rows written, never the tier; under a donated carry the stack is
+    updated where it lives."""
+    pos = stack.lengths[layer]  # (b,)
+    b = pos.shape[0]
+    act = jnp.ones((b,), bool) if active is None else active.astype(bool)
+    slot = jnp.arange(b, dtype=jnp.int32)
+    hot_cap = stack.hot_k.shape[2]
+    in_hot = pos < hot_cap
+
+    def put(tier, new, idx, write):
+        cap = tier.shape[2]
+        if cap == 0:  # zero-size tier (e.g. SWA: hot_cap=0) — nothing to write
+            return tier
+        rows = jnp.where(write & act, idx, cap)
+        new = _rows(new, 1, tier, stacked=True).astype(tier.dtype)
+        return tier.at[layer, slot, rows].set(new, mode="drop")
+
+    hot_idx = jnp.clip(pos, 0, max(hot_cap - 1, 0))
+    kw = dict(hot_k=put(stack.hot_k, k_new, hot_idx, in_hot),
+              hot_v=put(stack.hot_v, v_new, hot_idx, in_hot))
+    cold_pos = pos - hot_cap
+    if isinstance(stack, PagedKVCache):
+        assert not ring, "ring layout is not supported for paged caches"
+        n_pages, ps = stack.pool_k.shape[1:3]
+        pg = jnp.clip(cold_pos // ps, 0, stack.page_table.shape[2] - 1)
+        page = jnp.take_along_axis(stack.page_table[layer], pg[:, None],
+                                   axis=1)[:, 0]
+        page = jnp.where(~in_hot & act, page, n_pages)
+
+        def put_pool(pool, new):
+            new = _rows(new, 1, pool, stacked=True).astype(pool.dtype)
+            return pool.at[layer, page, cold_pos % ps].set(new, mode="drop")
+
+        kw.update(pool_k=put_pool(stack.pool_k, k_new),
+                  pool_v=put_pool(stack.pool_v, v_new))
+    else:
+        cold_cap = stack.cold_k.shape[2]
+        cold_idx = (cold_pos % max(cold_cap, 1) if ring
+                    else jnp.clip(cold_pos, 0, max(cold_cap - 1, 0)))
+        kw.update(cold_k=put(stack.cold_k, k_new, cold_idx, ~in_hot),
+                  cold_v=put(stack.cold_v, v_new, cold_idx, ~in_hot))
+    kw["lengths"] = stack.lengths.at[layer].set(pos + act.astype(jnp.int32))
+    return stack._replace(**kw)
+
+
+def layer_view(stack, layer):
+    """Layer ``layer`` of a layer-stacked cache, as an unstacked cache —
+    a copy of the layer's tiers, taken only on paths that cannot read the
+    stack in place (the XLA reference, MLA decode, whole-prompt
+    prefill)."""
+    return jax.tree.map(lambda a: a[layer], stack)
 
 
 def append_decode(
@@ -397,13 +373,17 @@ def append_decode(
     k_new: jax.Array,
     v_new: jax.Array,
     active: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> TieredKVCache:
     """Fast path for decode: append exactly one token (batch, ...) per slot.
 
     ``active`` (batch,) bool gates the write per slot: inactive slots keep
     their buffers and length untouched (continuous-batching retirement).
+    With ``layer`` the cache is layer-stacked and only layer ``layer``'s
+    rows and lengths change, in place.
     """
-    return _append_one(cache, k_new, v_new, active, ring=False)
+    return _on_layer(_append_one_at, cache, layer, k_new, v_new, active,
+                     False)
 
 
 def append_decode_ring(
@@ -411,14 +391,17 @@ def append_decode_ring(
     k_new: jax.Array,
     v_new: jax.Array,
     active: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> TieredKVCache:
     """Decode append with a *ring-buffer* cold tier (sliding-window archs).
 
     Position p ≥ hot_cap lands at cold slot (p - hot_cap) % cold_cap, so the
     cold tier holds exactly the last ``cold_cap`` tokens (SWA window) and
     early tokens are evicted — DR tiering uses hot_cap=0 here (DESIGN.md §4).
+    ``layer`` as in ``append_decode``.
     """
-    return _append_one(cache, k_new, v_new, active, ring=True)
+    return _on_layer(_append_one_at, cache, layer, k_new, v_new, active,
+                     True)
 
 
 # ---------------------------------------------------------------------------
@@ -453,11 +436,15 @@ def _valid_masks(cache: TieredKVCache):
 def _tier_partial(q, k, v, valid, scale):
     """Partial attention over one tier.
 
-    q: (b, h, d); k/v: (b, s, g, d) with g = kv heads (h = g * rep);
+    q: (b, h, d); k/v: (b, s, g·d) as stored, or (b, s, g, d), with g =
+    kv heads (h = g * rep);
     valid: (b, s) bool. Returns (numerator (b,h,d), denom (b,h), max (b,h)).
     """
-    b, s, g, d = k.shape
-    h = q.shape[1]
+    b, s = k.shape[:2]
+    h, d = q.shape[1:]
+    g = math.prod(k.shape[2:]) // d
+    k = k.reshape((b, s, g, d))  # stored (b, s, g·d) -> (b, s, g, d)
+    v = v.reshape((b, s, g, math.prod(v.shape[2:]) // g))
     if s == 0:  # zero-capacity tier: neutral element of the streaming merge
         dv = v.shape[-1]
         return (
@@ -586,6 +573,8 @@ def fill_fresh(
             "fill_fresh targets the contiguous cache (grouped admission); "
             "paged serving always streams prompts via chunked append")
     b, s = k_new.shape[:2]
+    k_new = _rows(k_new, 2, cache.hot_k)
+    v_new = _rows(v_new, 2, cache.hot_v)
     if ring and s > cache.cold_cap:
         w = cache.cold_cap
         # slot of token p is p % w; realign so slots match positions
@@ -674,7 +663,8 @@ def tiered_chunk_attention(
     neg = jnp.finfo(jnp.float32).min
 
     def partial(kbuf, vbuf, kpos, kvalid):
-        # kbuf: (b, S, g, dk); vbuf: (b, S, g, dv); kpos/kvalid: (b, S)
+        # kbuf: (b, S, g, dk); vbuf: (b, S, g, dv) (cache tiers arrive
+        # stored as (b, S, g·d)); kpos/kvalid: (b, S)
         s = kbuf.shape[1]
         if s == 0:
             return (
@@ -682,10 +672,11 @@ def tiered_chunk_attention(
                 jnp.zeros((b, g, rep, C), jnp.float32),
                 jnp.full((b, g, rep, C), neg),
             )
-        kf = _upcast(kbuf)
+        kf = _upcast(kbuf.reshape((b, s, g, dk)))
         # causally-masked rows hold real (finite) tokens, so per-row
         # kvalid zeroing suffices to keep NaN out of 0 * v products
-        vf = jnp.where(kvalid[:, :, None, None], _upcast(vbuf), 0.0)
+        vf = jnp.where(kvalid[:, :, None, None],
+                       _upcast(vbuf.reshape((b, s, g, dv))), 0.0)
         logits = jnp.einsum(
             "bgrcd,bsgd->bgrcs", qg.astype(kf.dtype), kf,
             preferred_element_type=jnp.float32,

@@ -24,7 +24,12 @@ length 37 pays for the whole cache. This kernel streams instead:
     materializes never exists;
   * **GQA folded into the q block** — the ``rep`` query heads of a kv
     group form the (rep, d) q tile of one grid row, so grouped heads
-    share each streamed KV tile.
+    share each streamed KV tile;
+  * **the layer read in place** (GQA forms) — given ``layer``, the cache
+    is the model's layer-stacked cache, stored in the kernel's (rows,
+    g·hd) layout, and every tier's index map leads with the scalar-
+    prefetched layer index: the decode step never slices a layer out of
+    the stack (``_gqa_launch``).
 
 Three entry points, mirroring the attention variants:
 
@@ -360,28 +365,93 @@ def _park_maps(hot_cap: int, cold_cap: int, bs_hot: int, bs_cold: int,
     return hot_map, cold_map
 
 
-def _cold_operands(cache, g, dk, dv, block_s, b):
-    """Cold-tier operands + blocking for a launch: contiguous caches use
-    the per-slot (b, cold_cap, g*d) buffers with ``block_s`` S-blocks;
-    paged caches stream the shared pool (n_pages, page_size, g*d) with
-    one page per S-block — the per-slot page table turns into gather
-    indices in the BlockSpec index map (``_paged_cold_map``)."""
-    if isinstance(cache, kvc.PagedKVCache):
-        ps = cache.page_size
-        ck = cache.pool_k.reshape(cache.n_pages, ps, g * dk)
-        cv = cache.pool_v.reshape(cache.n_pages, ps, g * dv)
-        return ck, cv, ps, cache.pages_per_slot
+def _stack_tier(buf, cap: int, block_s: int):
+    """``_tier_blocks`` for a layer-stacked tier (L, b, cap, D): returns
+    (operand, S-block, blocks, stacked). A zero-capacity tier becomes a
+    (1, b, 1, D) zeros dummy read at layer 0."""
+    if cap == 0:
+        dummy = jnp.zeros((1, buf.shape[1], 1, buf.shape[-1]), buf.dtype)
+        return dummy, 1, 1, False
+    bs = min(block_s, cap)
+    return buf, bs, pl.cdiv(cap, bs), True
 
-    def flat(t, d):
-        return t.reshape(b, t.shape[1], g * d)
 
-    dt = cache.hot_k.dtype
-    cold_cap = cache.cold_cap
-    ck, bs_cold, n_cold = _tier_blocks(
-        flat(cache.cold_k, dk), cold_cap, block_s, (b, 1, g * dk), dt)
-    cv, _, _ = _tier_blocks(
-        flat(cache.cold_v, dv), cold_cap, block_s, (b, 1, g * dv), dt)
-    return ck, cv, bs_cold, n_cold
+def _gqa_launch(q, cache, layer, block_s, fused):
+    """Operands, grid and specs shared by both GQA launches.
+
+    ``cache`` is layer-stacked (every leaf has a leading layer axis) and
+    the kernel reads layer ``layer`` of it in place: the layer index is a
+    scalar-prefetch operand and the leading coordinate of every tier's
+    index map, so no per-layer slice of the hot tier, the cold tier or
+    the page pool is ever made. The tiers are stored in the kernel's
+    layout — hot (L, b, hot, g·hd), contiguous cold (L, b, cold, g·hd),
+    pool (L, n_pages, page, g·hd) — and a (rows, d) block at group
+    coordinate g_i is kv group g_i's slice of the fused g·hd lane axis.
+    Paged caches stream one pool page per cold S-block, the slot's page
+    table (scalar prefetch) resolving logical to pool pages in the index
+    map. Scalar prefetch: (lengths, [active,] layer[, page table]).
+    """
+    b, h, dk = q.shape
+    g = cache.hot_k.shape[-1] // dk
+    rep = h // g
+    assert rep * g == h, (h, g)
+    dv = cache.hot_v.shape[-1] // g
+    paged = isinstance(cache, kvc.PagedKVCache)
+    hot_cap = cache.hot_k.shape[2]
+    if paged:
+        bs_cold, n_cold = cache.pool_k.shape[2], cache.page_table.shape[2]
+        cold_cap = bs_cold * n_cold
+    else:
+        cold_cap = cache.cold_k.shape[2]
+    if block_s is None:
+        block_s = ops.select_blocks(
+            rep, max(dk, dv), hot_cap + cold_cap, "pack2", kind="decode_attn"
+        )[2]
+    hk, bs_hot, n_hot, hot_st = _stack_tier(cache.hot_k, hot_cap, block_s)
+    hv, _, _, _ = _stack_tier(cache.hot_v, hot_cap, block_s)
+    if paged:
+        ck, cv, cold_st = cache.pool_k, cache.pool_v, True
+    else:
+        ck, bs_cold, n_cold, cold_st = _stack_tier(cache.cold_k, cold_cap,
+                                                   block_s)
+        cv, _, _, _ = _stack_tier(cache.cold_v, cold_cap, block_s)
+    i_layer = 2 if fused else 1
+    hot_map, cold_map = _park_maps(hot_cap, cold_cap, bs_hot, bs_cold, n_hot)
+    if paged:
+        cold_map = _paged_cold_map(hot_cap, cold_cap, bs_cold, n_hot)
+
+    def lead(pre, stacked):
+        return pre[i_layer][0] if stacked else 0
+
+    def hot_g(b_i, g_i, kk, *pre):
+        return (lead(pre, hot_st), *hot_map(b_i, kk, pre[0]), g_i)
+
+    def cold_g(b_i, g_i, kk, *pre):
+        if paged:
+            page, _ = cold_map(b_i, kk, pre[0], pre[i_layer + 1])
+            return lead(pre, cold_st), page, 0, g_i
+        return (lead(pre, cold_st), *cold_map(b_i, kk, pre[0]), g_i)
+
+    def q_map(b_i, g_i, kk, *pre):
+        return b_i, g_i, 0, 0
+
+    prefetch = [jnp.reshape(layer, (1,)).astype(jnp.int32)]
+    if paged:
+        prefetch.append(cache.page_table[layer].astype(jnp.int32))
+    in_specs = [
+        pl.BlockSpec((1, 1, rep, dk), q_map),
+        pl.BlockSpec((None, 1, bs_hot, dk), hot_g),
+        pl.BlockSpec((None, 1, bs_hot, dv), hot_g),
+        pl.BlockSpec((None, 1, bs_cold, dk), cold_g),
+        pl.BlockSpec((None, 1, bs_cold, dv), cold_g),
+    ]
+    blocks = dict(n_hot_blocks=n_hot, hot_cap=hot_cap, cold_cap=cold_cap)
+    return dict(
+        b=b, g=g, rep=rep, dk=dk, dv=dv, grid=(b, g, n_hot + n_cold),
+        lens=cache.lengths[layer].astype(jnp.int32), prefetch=prefetch,
+        tiers=(hk, hv, ck, cv), in_specs=in_specs, q_map=q_map,
+        blocks=blocks,
+    )
 
 
 def _paged_cold_map(hot_cap: int, cold_cap: int, page_size: int, n_hot: int):
@@ -401,70 +471,21 @@ def _paged_cold_map(hot_cap: int, cold_cap: int, page_size: int, n_hot: int):
     return cold_map
 
 
-def _flash_gqa(q, cache, scale, block_s, interpret):
-    b, h, dk = q.shape
-    g = cache.hot_k.shape[2]
-    rep = h // g
-    assert rep * g == h, (h, g)
-    dv = cache.hot_v.shape[-1]
-    hot_cap, cold_cap = cache.hot_cap, cache.cold_cap
-    paged = isinstance(cache, kvc.PagedKVCache)
-    if block_s is None:
-        block_s = ops.select_blocks(
-            rep, max(dk, dv), cache.capacity, "pack2", kind="decode_attn"
-        )[2]
+def _flash_gqa(q, cache, scale, block_s, interpret, layer=None):
+    cache, layer = kvc.as_stack(cache, layer)
+    la = _gqa_launch(q, cache, layer, block_s, fused=False)
+    b, g, rep, dk, dv = la["b"], la["g"], la["rep"], la["dk"], la["dv"]
+    body = functools.partial(_kernel_gqa, scale=scale, **la["blocks"])
+    n_pre = 1 + len(la["prefetch"])
 
-    # (b, s, g, d) -> (b, s, g*d): trailing-dim reshape (no copy), so the
-    # (1, bs, d) BlockSpec tiles land (sublane=s, lane=d)-aligned with the
-    # group picked by the block index along the fused g*d axis.
-    def flat(t, d):
-        return t.reshape(b, t.shape[1], g * d)
-
-    dt = cache.hot_k.dtype
-    hk, bs_hot, n_hot = _tier_blocks(
-        flat(cache.hot_k, dk), hot_cap, block_s, (b, 1, g * dk), dt)
-    hv, _, _ = _tier_blocks(
-        flat(cache.hot_v, dv), hot_cap, block_s, (b, 1, g * dv), dt)
-    ck, cv, bs_cold, n_cold = _cold_operands(cache, g, dk, dv, block_s, b)
-
-    hot_map2, cold_map2 = _park_maps(hot_cap, cold_cap, bs_hot, bs_cold, n_hot)
-    if paged:
-        cold_pt = _paged_cold_map(hot_cap, cold_cap, bs_cold, n_hot)
-        hot_g = lambda b_i, g_i, kk, lens, pt: (  # noqa: E731
-            *hot_map2(b_i, kk, lens), g_i)
-        cold_g = lambda b_i, g_i, kk, lens, pt: (  # noqa: E731
-            *cold_pt(b_i, kk, lens, pt), g_i)
-        q_map = lambda b_i, g_i, kk, lens, pt: (b_i, g_i, 0, 0)  # noqa: E731
-        prefetch = (cache.lengths.astype(jnp.int32),
-                    cache.page_table.astype(jnp.int32))
-        body = functools.partial(
-            _kernel_gqa, scale=scale, n_hot_blocks=n_hot,
-            hot_cap=hot_cap, cold_cap=cold_cap,
-        )
-        kern = lambda lens_ref, pt_ref, *rest: body(lens_ref, *rest)  # noqa: E731
-    else:
-        hot_g = lambda b_i, g_i, kk, lens: (  # noqa: E731
-            *hot_map2(b_i, kk, lens), g_i)
-        cold_g = lambda b_i, g_i, kk, lens: (  # noqa: E731
-            *cold_map2(b_i, kk, lens), g_i)
-        q_map = lambda b_i, g_i, kk, lens: (b_i, g_i, 0, 0)  # noqa: E731
-        prefetch = (cache.lengths.astype(jnp.int32),)
-        kern = functools.partial(
-            _kernel_gqa, scale=scale, n_hot_blocks=n_hot,
-            hot_cap=hot_cap, cold_cap=cold_cap,
-        )
+    def kern(lens_ref, *refs):
+        return body(lens_ref, *refs[n_pre - 1:])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(b, g, n_hot + n_cold),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, dk), q_map),
-            pl.BlockSpec((1, bs_hot, dk), hot_g),
-            pl.BlockSpec((1, bs_hot, dv), hot_g),
-            pl.BlockSpec((1, bs_cold, dk), cold_g),
-            pl.BlockSpec((1, bs_cold, dv), cold_g),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, dv), q_map),
+        num_scalar_prefetch=n_pre,
+        grid=la["grid"],
+        in_specs=la["in_specs"],
+        out_specs=pl.BlockSpec((1, 1, rep, dv), la["q_map"]),
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, 1), jnp.float32),
@@ -477,83 +498,42 @@ def _flash_gqa(q, cache, scale, block_s, interpret):
         out_shape=jax.ShapeDtypeStruct((b, g, rep, dv), q.dtype),
         interpret=interpret,
         name="flash_decode_attention",
-    )(*prefetch, q.reshape(b, g, rep, dk), hk, hv, ck, cv)
-    return out.reshape(b, h, dv)
+    )(la["lens"], *la["prefetch"], q.reshape(b, g, rep, dk), *la["tiers"])
+    return out.reshape(b, g * rep, dv)
 
 
 def _flash_gqa_fused(q, cache, k_new, v_new, active, scale, theta, ring,
-                     block_s, interpret):
+                     block_s, interpret, layer=None):
     """Launch the fused-RoPE decode kernel: unrotated q/k_new in, rotated
     k_new out alongside the attention output."""
-    b, h, dk = q.shape
-    g = cache.hot_k.shape[2]
-    rep = h // g
-    assert rep * g == h, (h, g)
-    dv = cache.hot_v.shape[-1]
-    hot_cap, cold_cap = cache.hot_cap, cache.cold_cap
-    if block_s is None:
-        block_s = ops.select_blocks(
-            rep, max(dk, dv), cache.capacity, "pack2", kind="decode_attn"
-        )[2]
-
-    def flat(t, d):
-        return t.reshape(b, t.shape[1], g * d)
-
-    dt = cache.hot_k.dtype
-    hk, bs_hot, n_hot = _tier_blocks(
-        flat(cache.hot_k, dk), hot_cap, block_s, (b, 1, g * dk), dt)
-    hv, _, _ = _tier_blocks(
-        flat(cache.hot_v, dv), hot_cap, block_s, (b, 1, g * dv), dt)
-    ck, cv, bs_cold, n_cold = _cold_operands(cache, g, dk, dv, block_s, b)
-
-    hot_map2, cold_map2 = _park_maps(hot_cap, cold_cap, bs_hot, bs_cold, n_hot)
-    paged = isinstance(cache, kvc.PagedKVCache)
+    cache, layer = kvc.as_stack(cache, layer)
+    assert not (ring and isinstance(cache, kvc.PagedKVCache)), (
+        "ring layout is not supported for paged caches")
+    la = _gqa_launch(q, cache, layer, block_s, fused=True)
+    b, g, rep, dk, dv = la["b"], la["g"], la["rep"], la["dk"], la["dv"]
     act = (
         jnp.ones((b,), jnp.int32) if active is None
         else active.astype(jnp.int32)
     )
-    body = functools.partial(
-        _kernel_gqa_fused, scale=scale, n_hot_blocks=n_hot,
-        hot_cap=hot_cap, cold_cap=cold_cap, ring=ring, theta=theta,
-    )
-    if paged:
-        assert not ring, "ring layout is not supported for paged caches"
-        cold_pt = _paged_cold_map(hot_cap, cold_cap, bs_cold, n_hot)
-        hot_g = lambda b_i, g_i, kk, lens, a, pt: (  # noqa: E731
-            *hot_map2(b_i, kk, lens), g_i)
-        cold_g = lambda b_i, g_i, kk, lens, a, pt: (  # noqa: E731
-            *cold_pt(b_i, kk, lens, pt), g_i)
-        q_map = lambda b_i, g_i, kk, lens, a, pt: (  # noqa: E731
-            b_i, g_i, 0, 0)
-        pin = lambda b_i, g_i, kk, lens, a, pt: (b_i, 0, g_i)  # noqa: E731
-        prefetch = (cache.lengths.astype(jnp.int32), act,
-                    cache.page_table.astype(jnp.int32))
-        kern = lambda lens_ref, act_ref, pt_ref, *rest: body(  # noqa: E731
-            lens_ref, act_ref, *rest)
-    else:
-        hot_g = lambda b_i, g_i, kk, lens, a: (  # noqa: E731
-            *hot_map2(b_i, kk, lens), g_i)
-        cold_g = lambda b_i, g_i, kk, lens, a: (  # noqa: E731
-            *cold_map2(b_i, kk, lens), g_i)
-        q_map = lambda b_i, g_i, kk, lens, a: (b_i, g_i, 0, 0)  # noqa: E731
-        pin = lambda b_i, g_i, kk, lens, a: (b_i, 0, g_i)  # noqa: E731
-        prefetch = (cache.lengths.astype(jnp.int32), act)
-        kern = body
+    body = functools.partial(_kernel_gqa_fused, scale=scale, ring=ring,
+                             theta=theta, **la["blocks"])
+    n_pre = 2 + len(la["prefetch"])
+
+    def kern(lens_ref, act_ref, *refs):
+        return body(lens_ref, act_ref, *refs[n_pre - 2:])
+
+    def pin(b_i, g_i, kk, *pre):
+        return b_i, 0, g_i
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(b, g, n_hot + n_cold),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, dk), q_map),
-            pl.BlockSpec((1, bs_hot, dk), hot_g),
-            pl.BlockSpec((1, bs_hot, dv), hot_g),
-            pl.BlockSpec((1, bs_cold, dk), cold_g),
-            pl.BlockSpec((1, bs_cold, dv), cold_g),
+        num_scalar_prefetch=n_pre,
+        grid=la["grid"],
+        in_specs=la["in_specs"] + [
             pl.BlockSpec((1, 1, dk), pin),
             pl.BlockSpec((1, 1, dv), pin),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rep, dv), q_map),
+            pl.BlockSpec((1, 1, rep, dv), la["q_map"]),
             pl.BlockSpec((1, 1, dk), pin),
         ],
         scratch_shapes=[
@@ -574,11 +554,11 @@ def _flash_gqa_fused(q, cache, k_new, v_new, active, scale, theta, ring,
         name=("flash_decode_attention_ring" if ring
               else "flash_decode_attention"),
     )(
-        *prefetch, q.reshape(b, g, rep, dk),
-        hk, hv, ck, cv, k_new.reshape(b, 1, g * dk),
+        la["lens"], act, *la["prefetch"], q.reshape(b, g, rep, dk),
+        *la["tiers"], k_new.reshape(b, 1, g * dk),
         v_new.reshape(b, 1, g * dv),
     )
-    return out.reshape(b, h, dv), k_rot.reshape(b, g, dk)
+    return out.reshape(b, g * rep, dv), k_rot.reshape(b, g, dk)
 
 
 def _flash_latent(q, cache, value_dim, scale, block_s, interpret):
@@ -632,26 +612,26 @@ def _flash_latent(q, cache, value_dim, scale, block_s, interpret):
 
 
 def _decode_entry(q, cache, scale, impl, block_s, interpret, k_new, v_new,
-                  active, rope_theta, ring):
+                  active, rope_theta, ring, layer):
     impl = _resolve(impl)
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"unknown impl {impl!r}")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
+    if impl == "xla" and layer is not None:
+        cache = kvc.layer_view(cache, layer)
     if k_new is None:
         if impl == "xla":
             return kvc.tiered_decode_attention(q, cache, scale)
-        if impl != "pallas":
-            raise ValueError(f"unknown impl {impl!r}")
         return _flash_gqa(q, cache, float(scale), block_s,
-                          _interpret(interpret))
+                          _interpret(interpret), layer)
     # fused-RoPE form: q and k_new are UNROTATED, the cache holds the
     # PRE-append state; returns (o, rotated k_new) — the caller appends.
     assert rope_theta is not None, "fused decode needs rope_theta"
     if impl == "pallas":
         return _flash_gqa_fused(
             q, cache, k_new, v_new, active, float(scale),
-            float(rope_theta), ring, block_s, _interpret(interpret),
+            float(rope_theta), ring, block_s, _interpret(interpret), layer,
         )
-    if impl != "xla":
-        raise ValueError(f"unknown impl {impl!r}")
     from repro.models.layers import apply_rope
 
     pos = cache.lengths.astype(jnp.int32)[:, None]  # (b, 1)
@@ -678,6 +658,7 @@ def flash_decode_attention(
     v_new: jax.Array | None = None,  # (b, g, dv)
     active: jax.Array | None = None,  # (b,) bool — slots really decoding
     rope_theta: float | None = None,
+    layer: jax.Array | None = None,  # () int32 — read this layer of a stack
 ) -> jax.Array:
     """One-token GQA attention over both tiers. q: (b, h, d) -> (b, h, d).
 
@@ -696,9 +677,15 @@ def flash_decode_attention(
     call returns ``(o, k_rot)`` so the caller's cache append consumes the
     kernel-rotated k — the decode step's separate XLA ``apply_rope``
     passes disappear. The cache argument is the PRE-append state.
+
+    With ``layer`` the cache is layer-stacked and the kernel reads layer
+    ``layer`` of it in place (the index is one more scalar-prefetch
+    operand; see ``_gqa_launch``); the XLA impl reads a copy of the
+    layer.
     """
     return _decode_entry(q, cache, scale, impl, block_s, interpret,
-                         k_new, v_new, active, rope_theta, ring=False)
+                         k_new, v_new, active, rope_theta, ring=False,
+                         layer=layer)
 
 
 @functools.partial(
@@ -717,6 +704,7 @@ def flash_decode_attention_ring(
     v_new: jax.Array | None = None,
     active: jax.Array | None = None,
     rope_theta: float | None = None,
+    layer: jax.Array | None = None,
 ) -> jax.Array:
     """GQA decode attention over a *ring-buffer* cold tier (SWA archs).
 
@@ -727,9 +715,11 @@ def flash_decode_attention_ring(
     The fused-RoPE form (``k_new``/``rope_theta``; pre-append cache) is
     where the layout matters: the kernel masks the ring slot the
     upcoming append will evict, keeping the wrapped window exact.
+    ``layer`` as in ``flash_decode_attention``.
     """
     return _decode_entry(q, cache, scale, impl, block_s, interpret,
-                         k_new, v_new, active, rope_theta, ring=True)
+                         k_new, v_new, active, rope_theta, ring=True,
+                         layer=layer)
 
 
 @functools.partial(

@@ -80,7 +80,7 @@ from repro.kernels.flash_decode import (
     _interpret,
     _resolve,
     _rope_rows,
-    _tier_blocks,
+    _stack_tier,
 )
 
 NEG_INF = jnp.finfo(jnp.float32).min
@@ -257,7 +257,7 @@ def _kernel_prefill(lens_ref, valid_ref, q_ref, hk_ref, hv_ref, ck_ref,
 
 def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
                    rope_dims, theta, emit_kv, kv_dtype, block_q, block_s,
-                   interpret):
+                   interpret, layer=None):
     b, c, h, dk = q.shape
     g = k_new.shape[2]
     rep = h // g
@@ -280,45 +280,37 @@ def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
     n_new = pl.cdiv(c, bs_new)
     ck_len = n_new * bs_new
 
+    if cache is None:  # fresh aligned prefill: no cached prefix
+        def empty(d):
+            return jnp.zeros((b, 0, g * d), k_new.dtype)
+
+        cache = kvc.TieredKVCache(empty(dk), empty(dv), empty(dk), empty(dv),
+                                  jnp.zeros((b,), jnp.int32))
+    # the tiers are stored in the kernel's (rows, g·d) layout (kv_cache)
+    # and read in place at layer ``layer`` of the stack, as flash-decode
+    # does (flash_decode._gqa_launch)
+    cache, layer = kvc.as_stack(cache, layer)
     paged = isinstance(cache, kvc.PagedKVCache)
-    if cache is None:
-        hot_cap = cold_cap = 0
-        lens = jnp.zeros((b,), jnp.int32)
-        hot_k = hot_v = cold_k = cold_v = None
-        tier_dt = k_new.dtype
-    else:
-        hot_cap, cold_cap = cache.hot_cap, cache.cold_cap
-        lens = cache.lengths.astype(jnp.int32)
-        hot_k, hot_v = cache.hot_k, cache.hot_v
-        cold_k, cold_v = (None, None) if paged else (cache.cold_k,
-                                                     cache.cold_v)
-        tier_dt = cache.hot_k.dtype
+    hot_cap = cache.hot_k.shape[2]
+    lens = cache.lengths[layer].astype(jnp.int32)
+    tier_dt = cache.hot_k.dtype
     kv_dtype = kv_dtype or tier_dt
-
-    def flat(t, d, cap):
-        if t is None:
-            return None
-        return t.reshape(b, cap, g * d)
-
-    hk, bs_hot, n_hot = _tier_blocks(
-        flat(hot_k, dk, hot_cap), hot_cap, block_s, (b, 1, g * dk), tier_dt)
-    hv, _, _ = _tier_blocks(
-        flat(hot_v, dv, hot_cap), hot_cap, block_s, (b, 1, g * dv), tier_dt)
+    hk, bs_hot, n_hot, hot_st = _stack_tier(cache.hot_k, hot_cap, block_s)
+    hv, _, _, _ = _stack_tier(cache.hot_v, hot_cap, block_s)
     if paged:
         # cold tier = the shared pool, one page per S-block; the per-slot
-        # page table rides as a third scalar-prefetch operand and resolves
-        # logical -> pool pages inside cold_map (flash_decode's scheme)
+        # page table rides as a fourth scalar-prefetch operand and
+        # resolves logical -> pool pages inside cold_map (flash_decode's
+        # scheme)
         assert not ring, "ring layout is not supported for paged caches"
-        bs_cold, n_cold = cache.page_size, cache.pages_per_slot
-        ck = cache.pool_k.reshape(cache.n_pages, bs_cold, g * dk)
-        cv = cache.pool_v.reshape(cache.n_pages, bs_cold, g * dv)
+        bs_cold, n_cold = cache.pool_k.shape[2], cache.page_table.shape[2]
+        cold_cap = bs_cold * n_cold
+        ck, cv, cold_st = cache.pool_k, cache.pool_v, True
     else:
-        ck, bs_cold, n_cold = _tier_blocks(
-            flat(cold_k, dk, cold_cap), cold_cap, block_s, (b, 1, g * dk),
-            tier_dt)
-        cv, _, _ = _tier_blocks(
-            flat(cold_v, dv, cold_cap), cold_cap, block_s, (b, 1, g * dv),
-            tier_dt)
+        cold_cap = cache.cold_k.shape[2]
+        ck, bs_cold, n_cold, cold_st = _stack_tier(cache.cold_k, cold_cap,
+                                                   block_s)
+        cv, _, _, _ = _stack_tier(cache.cold_v, cold_cap, block_s)
 
     # q: (b, c, h, dk) -> (b, g, cq*rep, dk), token-major rows per block
     qt = jnp.moveaxis(q.reshape(b, c, g, rep, dk), 1, 2)  # (b, g, c, rep, dk)
@@ -329,26 +321,21 @@ def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
     vn = jnp.pad(
         v_new.reshape(b, c, g * dv), ((0, 0), (0, ck_len - c), (0, 0)))
 
-    def hot_map(b_i, g_i, qi, kk, lens, valid, *rest):
+    def lead(lyr, stacked):
+        return lyr[0] if stacked else 0
+
+    def hot_map(b_i, g_i, qi, kk, lens, valid, lyr, *rest):
         nvalid = jnp.minimum(lens[b_i], hot_cap)
         nvb = jnp.maximum(pl.cdiv(nvalid, bs_hot), 1)
-        return b_i, jnp.minimum(kk, nvb - 1), g_i
+        return lead(lyr, hot_st), b_i, jnp.minimum(kk, nvb - 1), g_i
 
-    if paged:
-
-        def cold_map(b_i, g_i, qi, kk, lens, valid, pt):
-            nvalid = jnp.clip(lens[b_i] - hot_cap, 0, cold_cap)
-            nvb = jnp.maximum(pl.cdiv(nvalid, bs_cold), 1)
-            kc = jnp.maximum(kk - n_hot, 0)
-            return pt[b_i, jnp.minimum(kc, nvb - 1)], 0, g_i
-
-    else:
-
-        def cold_map(b_i, g_i, qi, kk, lens, valid, *rest):
-            nvalid = jnp.clip(lens[b_i] - hot_cap, 0, cold_cap)
-            nvb = jnp.maximum(pl.cdiv(nvalid, bs_cold), 1)
-            kc = jnp.maximum(kk - n_hot, 0)
-            return b_i, jnp.minimum(kc, nvb - 1), g_i
+    def cold_map(b_i, g_i, qi, kk, lens, valid, lyr, *pt):
+        nvalid = jnp.clip(lens[b_i] - hot_cap, 0, cold_cap)
+        nvb = jnp.maximum(pl.cdiv(nvalid, bs_cold), 1)
+        kc = jnp.minimum(jnp.maximum(kk - n_hot, 0), nvb - 1)
+        if paged:
+            return lead(lyr, cold_st), pt[0][b_i, kc], 0, g_i
+        return lead(lyr, cold_st), b_i, kc, g_i
 
     def new_map(b_i, g_i, qi, kk, lens, valid, *rest):
         kn_i = jnp.maximum(kk - n_hot - n_cold, 0)
@@ -365,10 +352,10 @@ def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
 
     in_specs = [
         pl.BlockSpec((1, 1, bq * rep, dk), q_map),
-        pl.BlockSpec((1, bs_hot, dk), hot_map),
-        pl.BlockSpec((1, bs_hot, dv), hot_map),
-        pl.BlockSpec((1, bs_cold, dk), cold_map),
-        pl.BlockSpec((1, bs_cold, dv), cold_map),
+        pl.BlockSpec((None, 1, bs_hot, dk), hot_map),
+        pl.BlockSpec((None, 1, bs_hot, dv), hot_map),
+        pl.BlockSpec((None, 1, bs_cold, dk), cold_map),
+        pl.BlockSpec((None, 1, bs_cold, dv), cold_map),
         pl.BlockSpec((1, bs_new, dk), new_map),
         pl.BlockSpec((1, bs_new, dv), new_map),
     ]
@@ -386,19 +373,19 @@ def _flash_prefill(q, k_new, v_new, cache, valid, scale, window, ring,
             pl.BlockSpec((1, bs_new, dv), emit_map),
         ]
 
-    prefetch = (lens, valid)
+    prefetch = [lens, valid, jnp.reshape(layer, (1,)).astype(jnp.int32)]
+    if paged:
+        prefetch.append(cache.page_table[layer].astype(jnp.int32))
     body = functools.partial(
         _kernel_prefill, scale=scale, n_hot=n_hot, n_cold=n_cold,
         hot_cap=hot_cap, cold_cap=cold_cap, bq=bq, rep=rep,
         window=window, ring=ring, rope_dims=rope_dims, theta=theta,
         emit_kv=emit_kv, k_in_dtype=k_new.dtype, v_in_dtype=v_new.dtype,
     )
-    if paged:
-        prefetch = (lens, valid, cache.page_table.astype(jnp.int32))
-        kern = lambda lens_ref, valid_ref, pt_ref, *rest: body(  # noqa: E731
-            lens_ref, valid_ref, *rest)
-    else:
-        kern = body
+
+    def kern(lens_ref, valid_ref, *refs):
+        return body(lens_ref, valid_ref, *refs[len(prefetch) - 2:])
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, g, nq, n_hot + n_cold + n_new),
@@ -457,6 +444,7 @@ def flash_prefill_attention(
     block_q: int | None = None,
     block_s: int | None = None,
     interpret: bool | None = None,
+    layer: jax.Array | None = None,  # () int32 — read this layer of a stack
 ):
     """Causal/SWA prefill attention over [tiered cache prefix ‖ chunk].
 
@@ -469,6 +457,10 @@ def flash_prefill_attention(
     tiers). ``impl``: "pallas" runs the streaming kernel (interpret mode
     on CPU), "xla" the ``kv_cache.tiered_chunk_attention`` reference,
     "auto" picks by backend.
+
+    With ``layer`` the cache is layer-stacked and the kernel reads layer
+    ``layer`` of it in place (one more scalar-prefetch operand, as in
+    ``flash_decode_attention``); the XLA impl reads a copy of the layer.
     """
     impl = _resolve(impl)
     b, c, h, dk = q.shape
@@ -481,10 +473,12 @@ def flash_prefill_attention(
         return _flash_prefill(
             q, k_new, v_new, cache, valid, scale, window, ring, rd,
             float(rope_theta), emit_kv, kv_dtype, block_q, block_s,
-            _interpret(interpret),
+            _interpret(interpret), layer,
         )
     if impl != "xla":
         raise ValueError(f"unknown impl {impl!r}")
+    if layer is not None:
+        cache = kvc.layer_view(cache, layer)
     offset = (
         cache.lengths.astype(jnp.int32)[:, None]
         if cache is not None else jnp.zeros((b, 1), jnp.int32)

@@ -277,11 +277,17 @@ def attention_prefill_chunk(
     n_valid: jax.Array,  # (b,) valid chunk rows; 0 = slot not prefilling
     impl: str | None = None,
     append: bool = True,
+    layer: jax.Array | None = None,  # () int32: this layer of a stacked cache
 ):
     """Chunked-prefill continuation for one layer: the C chunk tokens of
     each slot attend to the slot's cached prefix (``cache.lengths``
     tokens, both tiers) plus the causally-earlier rows of the chunk,
     then append their k/v at the slot's offset. Returns (y, cache).
+
+    With ``layer`` the cache is layer-stacked: the kernel reads layer
+    ``layer`` in place and the append writes its rows into the stack,
+    so the chunk step copies no tier (the XLA impl reads a copy of the
+    layer); the returned cache is the whole stack.
 
     With ``append=False`` the cache is left untouched and the rotated
     chunk k/v are returned instead: ``(y, (k_c, v_c))``. This is the
@@ -308,21 +314,23 @@ def attention_prefill_chunk(
             o, k_c, v_c = fprefill.flash_prefill_attention(
                 q, k, v, cache, valid=n_valid,
                 window=window, ring=swa, rope_theta=cfg.rope_theta,
-                emit_kv=True, impl="pallas",
+                emit_kv=True, impl="pallas", layer=layer,
             )
         else:
-            positions = cache.lengths.astype(jnp.int32)[:, None] + jnp.arange(
+            view = cache if layer is None else kvc.layer_view(cache, layer)
+            positions = view.lengths.astype(jnp.int32)[:, None] + jnp.arange(
                 c, dtype=jnp.int32
             )[None]
             qr = apply_rope(q, positions, cfg.rope_theta)
             kr = apply_rope(k, positions, cfg.rope_theta)
             o = kvc.tiered_chunk_attention(
-                qr, kr, v, cache, n_valid, window=window, ring=swa
+                qr, kr, v, view, n_valid, window=window, ring=swa
             )
             k_c, v_c = kr, v
     if append:
         with jax.named_scope("kv_write"):
-            cache = kvc.append(cache, k_c, v_c, valid=n_valid, ring=swa)
+            cache = kvc.append(cache, k_c, v_c, valid=n_valid, ring=swa,
+                               layer=layer)
     with jax.named_scope("o_proj"):
         y = qops.linear(
             p["wo"], o.reshape(b, c, h * hd), cfg, mode,
@@ -337,9 +345,12 @@ def attention_decode(
     cfg: ModelConfig,
     mode: str,
     cache: kvc.TieredKVCache,
+    layer: jax.Array,  # () int32: this block's layer in the stacked cache
     active: jax.Array | None = None,  # (b,) bool: slots that really decode
 ):
-    """One decode step against the tiered cache. Returns (y, new_cache).
+    """One decode step of layer ``layer`` against the layer-stacked tiered
+    cache. Returns (y, new_cache), the whole stack with layer ``layer``
+    advanced.
 
     RoPE positions come from the per-slot ``cache.lengths``, so slots at
     different sequence lengths decode side by side (continuous batching);
@@ -348,10 +359,12 @@ def attention_decode(
     the *fused-RoPE* form — q and the new token's k rotate in the kernel
     prologue, the pending (k, v) joins the softmax stream, and the cache
     append consumes the kernel-rotated k, so no separate XLA
-    ``apply_rope`` passes run in the decode step. The XLA impl keeps the
-    historical rotate → append → masked full-capacity read pipeline
-    (``qops.resolve_impl`` — the same dispatch rule as the packed
-    matmuls).
+    ``apply_rope`` passes run in the decode step. The kernel reads layer
+    ``layer``'s tiers from the stack in place and the append writes one
+    row per slot into it, so no tier is copied. The XLA impl keeps the
+    historical rotate → append → masked full-capacity read pipeline over
+    a copy of the layer (``qops.resolve_impl`` — the same dispatch rule
+    as the packed matmuls).
     """
     b, _ = x.shape
     h, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -365,19 +378,20 @@ def attention_decode(
             o, k_rot = entry(
                 q[:, 0], cache, impl=impl,
                 k_new=k[:, 0], v_new=v[:, 0], active=active,
-                rope_theta=cfg.rope_theta,
+                rope_theta=cfg.rope_theta, layer=layer,
             )
         with jax.named_scope("kv_write"):
-            cache = app(cache, k_rot, v[:, 0], active=active)
+            cache = app(cache, k_rot, v[:, 0], active=active, layer=layer)
     else:
         with jax.named_scope("attention"):
-            pos = cache.lengths[:, None]  # (b, 1) per-slot absolute position
+            # (b, 1) per-slot absolute position
+            pos = cache.lengths[layer][:, None]
             q = apply_rope(q, pos, cfg.rope_theta)[:, 0]  # (b,h,hd)
             k = apply_rope(k, pos, cfg.rope_theta)[:, 0]  # (b,g,hd)
         with jax.named_scope("kv_write"):
-            cache = app(cache, k, v[:, 0], active=active)
+            cache = app(cache, k, v[:, 0], active=active, layer=layer)
         with jax.named_scope("attention"):
-            o = entry(q, cache, impl=impl)
+            o = entry(q, cache, impl=impl, layer=layer)
     with jax.named_scope("o_proj"):
         y = qops.linear(
             p["wo"], o.reshape(b, h * hd), cfg, mode, lora_leaf=p.get("lora_o")
@@ -553,23 +567,28 @@ def mla_prefill(p, x, cfg: ModelConfig, mode, cache: kvc.TieredKVCache,
 
 
 def mla_decode(p, x, cfg: ModelConfig, mode, cache: kvc.TieredKVCache,
-               active: jax.Array | None = None):
-    """Absorbed-form MLA decode over the tiered latent cache.
+               layer: jax.Array, active: jax.Array | None = None):
+    """Absorbed-form MLA decode of layer ``layer`` over the layer-stacked
+    tiered latent cache.
 
     Per-slot positions from ``cache.lengths``; ``active`` gates the latent
-    append per slot (continuous batching).
+    append per slot (continuous batching). The append writes the stack in
+    place; attention reads a copy of the layer, because the read-time
+    fake-quant of the latent below makes one anyway.
     """
     m, h = cfg.mla, cfg.n_heads
     b, _ = x.shape
     hidden = rms_norm(x[:, None, :], p["ln"], cfg.norm_eps)
-    pos = cache.lengths[:, None]  # (b, 1)
+    pos = cache.lengths[layer][:, None]  # (b, 1)
     dq, dkv = _mla_down(p, hidden, cfg, mode)
     q_nope, q_rope = _mla_queries(p, dq, cfg, mode, pos)  # (b,1,h,·)
     c_kv, k_rope = _mla_latent(p, dkv, cfg, pos)
     lat_new = jnp.concatenate([c_kv, k_rope], axis=-1)[:, 0]  # (b, dl+dr)
     with jax.named_scope("kv_write"):
-        cache = kvc.append_decode(
-            cache, lat_new, jnp.zeros((b, 0), lat_new.dtype), active=active)
+        stack = kvc.append_decode(
+            cache, lat_new, jnp.zeros((b, 0), lat_new.dtype), active=active,
+            layer=layer)
+    cache = kvc.layer_view(stack, layer)
 
     # absorb W_uk into the query: q_abs = q_nope @ W_uk^T  (per head)
     w_uk = p["w_uk"]["w"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
@@ -608,4 +627,4 @@ def mla_decode(p, x, cfg: ModelConfig, mode, cache: kvc.TieredKVCache,
     with jax.named_scope("o_proj"):
         y = qops.linear(
             p["wo"], o.astype(x.dtype), cfg, mode, lora_leaf=p.get("lora_o"))
-    return y, cache
+    return y, stack

@@ -353,10 +353,12 @@ def _hybrid_forward(params, cfg, x, mode, positions, remat, collect_kv):
 
 
 def _attn_cache_spec(cfg: ModelConfig):
+    """Per-token k/v storage shapes. GQA stores (g·hd,), the flash
+    kernels' operand layout (core/kv_cache.py)."""
     if cfg.attn_type == "mla":
         return (cfg.mla.kv_cache_dim,), (0,)
     g, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    return (g, hd), (g, hd)
+    return (g * hd,), (g * hd,)
 
 
 def init_decode_cache(
@@ -520,37 +522,58 @@ def prefill(
     return logits[:, -1], cache
 
 
-def _attn_block_prefill(bp, x, cfg, mode, cache_layer, n_valid=None):
-    """One block of the flash-prefill scan: full-seq attention straight
-    into the tiered cache rows, then the MLP/MoE. ``n_valid`` switches
-    the chunked continuation form (serving engine)."""
+def _attn_block_prefill(bp, x, cfg, mode, cache_stack, layer, n_valid=None):
+    """One block of the flash-prefill scan over the layer-stacked cache:
+    full-seq attention straight into the tiered cache rows, then the
+    MLP/MoE. ``n_valid`` switches the chunked continuation form (serving
+    engine), which reads and writes layer ``layer`` of the stack in
+    place; the whole-prompt forms fill a copy of the layer and write it
+    back. Returns (x, cache_stack)."""
     if n_valid is not None:
-        y, cache_layer = attn.attention_prefill_chunk(
-            bp["attn"], x, cfg, mode, cache_layer, n_valid
+        y, cache_stack = attn.attention_prefill_chunk(
+            bp["attn"], x, cfg, mode, cache_stack, n_valid, layer=layer
         )
-    elif cfg.attn_type == "mla":
-        y, cache_layer = attn.mla_prefill(bp["attn"], x, cfg, mode, cache_layer)
     else:
-        y, cache_layer = attn.attention_prefill(bp["attn"], x, cfg, mode, cache_layer)
+        f = attn.mla_prefill if cfg.attn_type == "mla" else attn.attention_prefill
+        y, cache_layer = f(bp["attn"], x, cfg, mode,
+                           kvc.layer_view(cache_stack, layer))
+        cache_stack = jax.tree.map(lambda s, a: s.at[layer].set(a),
+                                   cache_stack, cache_layer)
     x = x + y
     with jax.named_scope("mlp"):
         if "moe" in bp:
             h, _ = moe_lib.apply_moe(bp["moe"], x, cfg, mode)
         else:
             h = apply_mlp(bp["mlp"], x, cfg, mode)
-    return x + h, cache_layer
+    return x + h, cache_stack
+
+
+def _scan_layers(block, x, stack_params, cache_stack):
+    """Scan ``block(bp, x, cache_stack, layer) -> (x, cache_stack)`` over
+    the stacked params and their layer indices. The stacked cache rides
+    the carry, so each block reads and writes its layer of the stack
+    where it lives (under the engine's donation, the input buffer)."""
+
+    def step(carry, xs):
+        h, cs = carry
+        bp, layer = xs
+        return block(bp, h, cs, layer), None
+
+    n = cache_stack.lengths.shape[0]
+    (x, cache_stack), _ = jax.lax.scan(
+        step, (x, cache_stack), (stack_params, jnp.arange(n, dtype=jnp.int32)))
+    return x, cache_stack
 
 
 def _prefill_scan(params, cfg, x, cache, mode, n_valid=None):
-    """Scan the stacked attention blocks over (params, cache) pairs —
-    decode_step's structure at full sequence length."""
+    """Scan the stacked attention blocks with the stacked cache in the
+    carry — decode_step's structure at full sequence length."""
 
     def scan_attn(x1, stack_params, cache_stack):
-        def step(h, xs):
-            bp, cl = xs
-            return _attn_block_prefill(bp, h, cfg, mode, cl, n_valid)
-
-        return jax.lax.scan(step, x1, (stack_params, cache_stack))
+        return _scan_layers(
+            lambda bp, h, cs, layer: _attn_block_prefill(
+                bp, h, cfg, mode, cs, layer, n_valid),
+            x1, stack_params, cache_stack)
 
     with jax.named_scope("layers"):
         if cfg.family in ("dense", "vlm"):
@@ -698,9 +721,10 @@ def spec_commit_chunk(cfg: ModelConfig, cache, kvs, n_commit: jax.Array):
 # ---------------------------------------------------------------------------
 
 
-def _attn_block_decode(bp, x1, cfg, mode, cache_layer, active=None):
+def _attn_block_decode(bp, x1, cfg, mode, cache_stack, layer, active=None):
     f = attn.mla_decode if cfg.attn_type == "mla" else attn.attention_decode
-    y, cache_layer = f(bp["attn"], x1, cfg, mode, cache_layer, active=active)
+    y, cache_stack = f(bp["attn"], x1, cfg, mode, cache_stack, layer,
+                       active=active)
     x1 = x1 + y
     with jax.named_scope("mlp"):
         if "moe" in bp:
@@ -708,7 +732,7 @@ def _attn_block_decode(bp, x1, cfg, mode, cache_layer, active=None):
             h = h[:, 0]
         else:
             h = apply_mlp(bp["mlp"], x1[:, None, :], cfg, mode)[:, 0]
-    return x1 + h, cache_layer
+    return x1 + h, cache_stack
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array, cache,
@@ -720,17 +744,21 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array, cache,
     slot: inactive slots (retired or unadmitted, in continuous batching)
     still flow through the compute — their logits are garbage and ignored
     by the caller — but neither append KV nor advance recurrent state.
+
+    Each attention stack's cache rides the layer scan's carry and each
+    block gets its layer index: attention reads layer l of the stack in
+    place and the append writes one row per slot into it, so under the
+    engine's donation the step updates the cache where it lives and never
+    copies a layer's tiers.
     """
     dtype = params["final_ln"].dtype
     x = _embed_tokens(params, cfg, tokens[:, None], dtype)[:, 0]  # (b, d)
 
     def scan_attn(x1, stack_params, cache_stack):
-        def step(h, xs):
-            bp, cl = xs
-            h2, cl2 = _attn_block_decode(bp, h, cfg, mode, cl, active)
-            return h2, cl2
-
-        return jax.lax.scan(step, x1, (stack_params, cache_stack))
+        return _scan_layers(
+            lambda bp, h, cs, layer: _attn_block_decode(
+                bp, h, cfg, mode, cs, layer, active),
+            x1, stack_params, cache_stack)
 
     def _mask_state(new_state, old_state):
         if active is None:
@@ -764,30 +792,26 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array, cache,
             x, cache["ssm"] = scan_ssm(x, params["blocks"], cache["ssm"])
         elif cfg.family == "hybrid":
 
-            def group_step(h, xs):
-                gp, gstate, acache, lora_v = xs
+            # the shared attention block's cache is stacked per group: it
+            # rides the carry like scan_attn's, the group index is its layer
+            def group_step(carry, xs):
+                h, acache = carry
+                gp, gstate, layer, lora_v = xs
                 h, gstate2 = scan_ssm(h, gp, gstate)
                 sp = {"attn": params["shared"]["attn"], "mlp": params["shared"]["mlp"]}
                 if lora_v is not None:
                     sp = {"attn": {**sp["attn"], "lora_v": lora_v}, "mlp": sp["mlp"]}
-                h, acache2 = _attn_block_decode(sp, h, cfg, mode, acache, active)
-                return h, (gstate2, acache2)
+                h, acache = _attn_block_decode(sp, h, cfg, mode, acache, layer,
+                                               active)
+                return (h, acache), gstate2
 
-            lora_stack = params.get("shared_lora_v")
-            if lora_stack is None:
-                def step(h, xs_i):
-                    gp, gstate, acache = xs_i
-                    return group_step(h, (gp, gstate, acache, None))
-                x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
-                    step, x, (params["mamba_groups"], cache["mamba"], cache["attn"])
-                )
-            else:
-                def step(h, xs_i):
-                    gp, gstate, acache, lv = xs_i
-                    return group_step(h, (gp, gstate, acache, lv))
-                x, (cache["mamba"], cache["attn"]) = jax.lax.scan(
-                    step, x, (params["mamba_groups"], cache["mamba"], cache["attn"], lora_stack)
-                )
+            n_groups = cache["attn"].lengths.shape[0]
+            (x, cache["attn"]), cache["mamba"] = jax.lax.scan(
+                group_step, (x, cache["attn"]),
+                (params["mamba_groups"], cache["mamba"],
+                 jnp.arange(n_groups, dtype=jnp.int32),
+                 params.get("shared_lora_v")),
+            )
             if "tail" in cache:
                 x, cache["tail"] = scan_ssm(x, params["mamba_tail"], cache["tail"])
         else:  # pragma: no cover

@@ -48,9 +48,10 @@ TOL = dict(rtol=2e-5, atol=2e-5)
 def _build_cache(b, hot, cold, g, d, lens, dtype=jnp.float32, ring=False,
                  seed=0):
     """Cache with per-slot lengths built via active-masked decode appends
-    (the continuous-batching write path). Returns (cache, ks, vs) with
-    ks/vs the full (b, max_len, g, d) f32 history."""
-    cache = kvc.init_cache(b, hot, cold, (g, d), dtype)
+    (the continuous-batching write path), in the stored (g·d) layout.
+    Returns (cache, ks, vs) with ks/vs the full (b, max_len, g, d) f32
+    history."""
+    cache = kvc.init_cache(b, hot, cold, (g * d,), dtype)
     t_max = max(max(lens), 1)
     ks = jax.random.normal(jax.random.PRNGKey(seed), (b, t_max, g, d))
     vs = jax.random.normal(jax.random.PRNGKey(seed + 1), (b, t_max, g, d))
@@ -79,9 +80,11 @@ def _cache_prefix(cache, i):
 
 def _oracle_slot(q_i, ks, vs, scale):
     """Plain f32 softmax attention for ONE slot. q_i: (h, d); ks/vs:
-    (t, g, d). Returns (h, dv); zeros for an empty prefix."""
-    h = q_i.shape[0]
-    t, g, d = ks.shape
+    (t, g·d) as stored. Returns (h, dv); zeros for an empty prefix."""
+    h, d = q_i.shape
+    t, g = ks.shape[0], ks.shape[1] // d
+    ks = np.asarray(ks, np.float32).reshape(t, g, d)
+    vs = np.asarray(vs, np.float32).reshape(t, g, vs.shape[1] // g)
     if t == 0:
         return np.zeros((h, vs.shape[-1]), np.float32)
     rep = h // g
@@ -281,6 +284,15 @@ def test_latent_small_blocks():
 # ---------------------------------------------------------------------------
 
 
+LAYER = jnp.int32(0)
+
+
+def _stack(cache):
+    """A one-layer stack: the decode-step form attention_decode and
+    mla_decode take (a leading layer axis, read at ``LAYER``)."""
+    return jax.tree.map(lambda a: a[None], cache)
+
+
 def _impl_cfg(cfg, impl):
     return dataclasses.replace(
         cfg, bitnet=dataclasses.replace(cfg.bitnet, impl=impl)
@@ -295,7 +307,7 @@ def test_attention_decode_impl_parity():
     p = attn.init_attention(jax.random.PRNGKey(0), cfg)
     g, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     b = 3
-    cache = kvc.init_cache(b, 4, 12, (g, hd), jnp.float32)
+    cache = _stack(kvc.init_cache(b, 4, 12, (g * hd,), jnp.float32))
     x_hist = jax.random.normal(jax.random.PRNGKey(1), (8, b, cfg.d_model)) * 0.1
     lens = [2, 0, 7]
     outs = {}
@@ -304,10 +316,11 @@ def test_attention_decode_impl_parity():
         for t in range(7):
             active = jnp.asarray([t < L for L in lens])
             _, c = attn.attention_decode(
-                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, active=active
+                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, LAYER,
+                active=active
             )
         y, c = attn.attention_decode(
-            p, x_hist[7], _impl_cfg(cfg, impl), "qat", c
+            p, x_hist[7], _impl_cfg(cfg, impl), "qat", c, LAYER
         )
         outs[impl] = (np.asarray(y), np.asarray(c.lengths))
     np.testing.assert_array_equal(outs["pallas"][1], outs["xla"][1])
@@ -322,9 +335,9 @@ def test_mla_decode_impl_parity():
     p = attn.init_mla(jax.random.PRNGKey(0), cfg)
     b, dd = 2, cfg.mla.kv_cache_dim
     cache = kvc.init_cache(b, 2, 6, (dd,), jnp.float32)
-    cache = cache._replace(
+    cache = _stack(cache._replace(
         hot_v=jnp.zeros((b, 2, 0)), cold_v=jnp.zeros((b, 6, 0))
-    )
+    ))
     x_hist = jax.random.normal(jax.random.PRNGKey(1), (5, b, cfg.d_model)) * 0.1
     outs = {}
     for impl in ("pallas", "xla"):
@@ -332,9 +345,11 @@ def test_mla_decode_impl_parity():
         for t in range(4):
             active = jnp.asarray([True, t < 2])
             _, c = attn.mla_decode(
-                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, active=active
+                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, LAYER,
+                active=active
             )
-        y, c = attn.mla_decode(p, x_hist[4], _impl_cfg(cfg, impl), "qat", c)
+        y, c = attn.mla_decode(p, x_hist[4], _impl_cfg(cfg, impl), "qat", c,
+                               LAYER)
         outs[impl] = (np.asarray(y), np.asarray(c.lengths))
     np.testing.assert_array_equal(outs["pallas"][1], outs["xla"][1])
     np.testing.assert_allclose(outs["pallas"][0], outs["xla"][0],
@@ -408,7 +423,7 @@ def test_attention_decode_fused_vs_xla_path():
     p = attn.init_attention(jax.random.PRNGKey(0), cfg)
     g, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     b = 3
-    cache = kvc.init_cache(b, 4, 12, (g, hd), jnp.float32)
+    cache = _stack(kvc.init_cache(b, 4, 12, (g * hd,), jnp.float32))
     x_hist = jax.random.normal(jax.random.PRNGKey(1), (8, b, cfg.d_model)) * 0.1
     lens = [2, 0, 7]
     outs = {}
@@ -417,9 +432,11 @@ def test_attention_decode_fused_vs_xla_path():
         for t in range(7):
             active = jnp.asarray([t < L for L in lens])
             _, c = attn.attention_decode(
-                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, active=active
+                p, x_hist[t], _impl_cfg(cfg, impl), "qat", c, LAYER,
+                active=active
             )
-        y, c = attn.attention_decode(p, x_hist[7], _impl_cfg(cfg, impl), "qat", c)
+        y, c = attn.attention_decode(p, x_hist[7], _impl_cfg(cfg, impl), "qat",
+                                     c, LAYER)
         outs[impl] = (np.asarray(y), c)
     np.testing.assert_array_equal(
         np.asarray(outs["pallas"][1].lengths), np.asarray(outs["xla"][1].lengths))

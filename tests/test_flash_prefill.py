@@ -53,13 +53,14 @@ THETA = 1e4
 
 def _build_cache(b, hot, cold, g, d, lens, dv=None, dtype=jnp.float32,
                  ring=False, seed=0):
-    """Cache with per-slot lengths via active-masked decode appends."""
+    """Cache with per-slot lengths via active-masked decode appends, in
+    the stored (g·d) layout."""
     dv = dv or d
-    cache = kvc.init_cache(b, hot, cold, (g, d), dtype)
+    cache = kvc.init_cache(b, hot, cold, (g * d,), dtype)
     if dv != d:
         cache = cache._replace(
-            hot_v=jnp.zeros((b, hot, g, dv), dtype),
-            cold_v=jnp.zeros((b, cold, g, dv), dtype),
+            hot_v=jnp.zeros((b, hot, g * dv), dtype),
+            cold_v=jnp.zeros((b, cold, g * dv), dtype),
         )
     app = kvc.append_decode_ring if ring else kvc.append_decode
     for t in range(max(lens)):
@@ -202,10 +203,12 @@ def test_continuation_oracle_prefix_plus_chunk():
         n_hot = min(L, 4)
         ks_hist = np.concatenate(
             [np.asarray(cache.hot_k[i, :n_hot], np.float64),
-             np.asarray(cache.cold_k[i, : L - n_hot], np.float64)])
+             np.asarray(cache.cold_k[i, : L - n_hot], np.float64)]
+        ).reshape(L, g, d)
         vs_hist = np.concatenate(
             [np.asarray(cache.hot_v[i, :n_hot], np.float64),
-             np.asarray(cache.cold_v[i, : L - n_hot], np.float64)])
+             np.asarray(cache.cold_v[i, : L - n_hot], np.float64)]
+        ).reshape(L, g, d)
         for t in range(c):
             for hh in range(h):
                 gg = hh // rep
@@ -291,7 +294,7 @@ def test_fill_matches_fill_attn_cache_oracle(dtype):
     # placement + cast parity, bitwise, on the same rotated rows:
     # fill_fresh (the static-slice path _fill_attn_cache now delegates
     # to) vs the historical one-hot append on a fresh cache
-    fresh = kvc.init_cache(b, hot, cold, (g, d), dtype)
+    fresh = kvc.init_cache(b, hot, cold, (g * d,), dtype)
     filled = kvc.fill_fresh(fresh, k_c, v_c)
     legacy = kvc.append(fresh, k_c, v_c)
     for name in ("hot_k", "hot_v", "cold_k", "cold_v", "lengths"):
@@ -313,17 +316,19 @@ def test_fill_swa_ring_realign_matches_legacy():
         q, k, v, None, impl="pallas", rope_theta=THETA, emit_kv=True,
         window=w, block_q=4, block_s=4,
     )
-    fresh = kvc.init_cache(b, 0, w, (g, d), jnp.float32)
+    fresh = kvc.init_cache(b, 0, w, (g * d,), jnp.float32)
     filled = kvc.fill_fresh(fresh, k_c, v_c, ring=True)
     np.testing.assert_array_equal(np.asarray(filled.lengths), [s, s])
     for j in range(w):
         p = max(pp for pp in range(s) if pp % w == j)  # last writer of slot j
         np.testing.assert_array_equal(
-            np.asarray(filled.cold_k[:, j]), np.asarray(k_c[:, p]),
+            np.asarray(filled.cold_k[:, j]),
+            np.asarray(k_c[:, p]).reshape(b, g * d),
             err_msg=f"slot {j} != position {p}",
         )
         np.testing.assert_array_equal(
-            np.asarray(filled.cold_v[:, j]), np.asarray(v_c[:, p]))
+            np.asarray(filled.cold_v[:, j]),
+            np.asarray(v_c[:, p]).reshape(b, g * d))
     # and ring append on a fresh cache (the decode write path) agrees
     legacy = kvc.append(fresh, k_c, v_c, ring=True)
     for name in ("cold_k", "cold_v", "lengths"):

@@ -10,7 +10,8 @@ from repro.core import kv_cache
 
 
 def _mk(batch=2, hot=4, cold=12, heads=2, dim=8, dtype=jnp.float32):
-    return kv_cache.init_cache(batch, hot, cold, (heads, dim), dtype)
+    """A cache in the stored layout: (heads·dim,) per token."""
+    return kv_cache.init_cache(batch, hot, cold, (heads * dim,), dtype)
 
 
 def test_append_routes_early_tokens_hot():
@@ -21,9 +22,9 @@ def test_append_routes_early_tokens_hot():
         cache = kv_cache.append_decode(cache, k, k * 10)
     np.testing.assert_array_equal(np.asarray(cache.lengths), [6, 6])
     # tokens 0..3 in hot, 4..5 in cold
-    np.testing.assert_allclose(np.asarray(cache.hot_k[0, :, 0, 0]), [1, 2, 3, 4])
-    np.testing.assert_allclose(np.asarray(cache.cold_k[0, :2, 0, 0]), [5, 6])
-    np.testing.assert_allclose(np.asarray(cache.cold_v[0, :2, 0, 0]), [50, 60])
+    np.testing.assert_allclose(np.asarray(cache.hot_k[0, :, 0]), [1, 2, 3, 4])
+    np.testing.assert_allclose(np.asarray(cache.cold_k[0, :2, 0]), [5, 6])
+    np.testing.assert_allclose(np.asarray(cache.cold_v[0, :2, 0]), [50, 60])
 
 
 def test_bulk_append_matches_decode_appends():
@@ -95,7 +96,7 @@ def test_append_is_jittable_and_scan_safe():
 def test_mixed_length_slots_attention_matches_oracle():
     """Slots at different lengths each attend to exactly their own prefix."""
     b, hot, cold = 3, 4, 12
-    cache = kv_cache.init_cache(b, hot, cold, (2, 8), jnp.float32)
+    cache = kv_cache.init_cache(b, hot, cold, (2 * 8,), jnp.float32)
     lens = [2, 9, 14]
     ks = jax.random.normal(jax.random.PRNGKey(10), (b, 16, 2, 8))
     vs = jax.random.normal(jax.random.PRNGKey(11), (b, 16, 2, 8))
@@ -119,7 +120,8 @@ def test_inactive_slots_do_not_write():
     cache = kv_cache.append_decode(cache, k1, k1, active=jnp.asarray([True, False]))
     np.testing.assert_array_equal(np.asarray(cache.lengths), [1, 0])
     assert float(jnp.abs(cache.hot_k[1]).sum()) == 0.0
-    np.testing.assert_allclose(np.asarray(cache.hot_k[0, 0]), np.asarray(k1[0]))
+    np.testing.assert_allclose(np.asarray(cache.hot_k[0, 0]),
+                               np.asarray(k1[0]).reshape(-1))
 
 
 def test_per_slot_bulk_append_from_unequal_starts():
@@ -133,9 +135,9 @@ def test_per_slot_bulk_append_from_unequal_starts():
     cache = kv_cache.append(cache, ks, ks)
     np.testing.assert_array_equal(np.asarray(cache.lengths), [5, 2])
     # slot 0: positions 3,4 -> cold slots 1,2 (hot_cap=2)
-    np.testing.assert_allclose(np.asarray(cache.cold_k[0, 1:3, 0, 0]), [7, 7])
+    np.testing.assert_allclose(np.asarray(cache.cold_k[0, 1:3, 0]), [7, 7])
     # slot 1: positions 0,1 -> hot slots 0,1
-    np.testing.assert_allclose(np.asarray(cache.hot_k[1, :2, 0, 0]), [9, 9])
+    np.testing.assert_allclose(np.asarray(cache.hot_k[1, :2, 0]), [9, 9])
 
 
 def test_ring_cold_tier_wraparound_per_slot():
@@ -143,7 +145,7 @@ def test_ring_cold_tier_wraparound_per_slot():
     at slot (p - hot_cap) % cold_cap, including after wrap-around — and
     slots can wrap independently."""
     b, hot, cold = 2, 0, 4
-    cache = kv_cache.init_cache(b, hot, cold, (1, 4), jnp.float32)
+    cache = kv_cache.init_cache(b, hot, cold, (1 * 4,), jnp.float32)
     lens = [7, 3]  # slot 0 wraps (7 > 4), slot 1 does not
     for t in range(7):
         k = jnp.stack([jnp.full((1, 4), float(10 + t)), jnp.full((1, 4), float(20 + t))])
@@ -154,19 +156,61 @@ def test_ring_cold_tier_wraparound_per_slot():
     want0 = [0.0] * 4
     for p in range(3, 7):
         want0[p % 4] = 10.0 + p
-    np.testing.assert_allclose(np.asarray(cache.cold_k[0, :, 0, 0]), want0)
+    np.testing.assert_allclose(np.asarray(cache.cold_k[0, :, 0]), want0)
     # slot 1 holds tokens 0..2 in order, last ring slot untouched
-    np.testing.assert_allclose(np.asarray(cache.cold_k[1, :, 0, 0]), [20, 21, 22, 0])
+    np.testing.assert_allclose(np.asarray(cache.cold_k[1, :, 0]), [20, 21, 22, 0])
     # validity clamps at cold_cap: all 4 positions valid for the wrapped
     # slot, 3 for the unwrapped one
     q = jax.random.normal(jax.random.PRNGKey(13), (b, 1, 4))
     got = kv_cache.tiered_decode_attention(q, cache)
-    ks0 = cache.cold_k[0:1]  # ring content (order irrelevant to attention)
+    # ring content (order irrelevant to attention), as (b, t, g, d)
+    ks0 = cache.cold_k[0:1].reshape(1, 4, 1, 4)
     want = _oracle_attention(q[0:1], ks0, ks0)
     np.testing.assert_allclose(np.asarray(got[0:1]), np.asarray(want), rtol=2e-5, atol=2e-5)
-    ks1 = cache.cold_k[1:2, :3]
+    ks1 = cache.cold_k[1:2, :3].reshape(1, 3, 1, 4)
     want1 = _oracle_attention(q[1:2], ks1, ks1)
     np.testing.assert_allclose(np.asarray(got[1:2]), np.asarray(want1), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["contiguous", "ring", "paged"])
+def test_stacked_decode_append_writes_only_its_layer(kind):
+    """append_decode(..., layer=l) on a layer-stacked cache (the decode
+    step's form) changes layer l exactly as the unstacked append changes
+    that layer alone, and leaves every other layer's rows and lengths as
+    they were."""
+    n_layers, b, g, d = 3, 3, 2, 8
+    ring = kind == "ring"
+    hot, cold = (0, 6) if ring else (4, 12)
+    if kind == "paged":
+        one = kv_cache.init_paged_cache(b, hot, cold, (g * d,), jnp.float32,
+                                        page_size=4)
+    else:
+        one = kv_cache.init_cache(b, hot, cold, (g * d,), jnp.float32)
+    app = kv_cache.append_decode_ring if ring else kv_cache.append_decode
+    key = jax.random.PRNGKey(20)
+    stack = jax.tree.map(
+        lambda a: jnp.broadcast_to(a, (n_layers,) + a.shape), one)
+    stack = stack._replace(
+        hot_k=jax.random.normal(key, stack.hot_k.shape),
+        lengths=jnp.broadcast_to(jnp.asarray([3, 7, 11], jnp.int32),
+                                 stack.lengths.shape))
+    active = jnp.asarray([True, False, True])
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, g, d))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, g, d))
+    layer = 1
+    got = jax.jit(lambda s, kk, vv, l: app(s, kk, vv, active=active,
+                                          layer=l))(stack, k, v,
+                                                    jnp.int32(layer))
+    want = app(kv_cache.layer_view(stack, layer), k, v, active=active)
+    for name in stack._fields:
+        a, w0 = getattr(got, name), getattr(stack, name)
+        np.testing.assert_array_equal(np.asarray(a[layer]),
+                                      np.asarray(getattr(want, name)),
+                                      err_msg=name)
+        for other in (0, 2):
+            np.testing.assert_array_equal(np.asarray(a[other]),
+                                          np.asarray(w0[other]),
+                                          err_msg=name)
 
 
 # ---------------------------------------------------------------------------

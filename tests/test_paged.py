@@ -36,6 +36,7 @@ from repro.core import kv_cache as kvc
 from repro.kernels import flash_decode as fd
 from repro.kernels import flash_prefill as fp
 from repro.kernels import ops
+from repro.models import pack as pack_lib
 from repro.models import transformer as T
 from repro.serving import engine as engine_mod
 from repro.serving.engine import PAD_TOKEN, Engine
@@ -54,10 +55,11 @@ THETA = 1e4
 def _build_pair(b, hot, cold, g, d, lens, ps=8, dtype=jnp.float32, seed=0,
                 n_pages=None):
     """Contiguous + paged caches filled with IDENTICAL per-slot content
-    via active-masked decode appends (mixed lengths)."""
-    cont = kvc.init_cache(b, hot, cold, (g, d), dtype)
+    via active-masked decode appends (mixed lengths), in the stored (g·d)
+    layout."""
+    cont = kvc.init_cache(b, hot, cold, (g * d,), dtype)
     paged = kvc.init_paged_cache(
-        b, hot, cold, (g, d), dtype, page_size=ps, n_pages=n_pages
+        b, hot, cold, (g * d,), dtype, page_size=ps, n_pages=n_pages
     )
     key = jax.random.PRNGKey(seed)
     for t in range(max(lens)):
@@ -314,7 +316,7 @@ def test_save_hot_paged_admit_roundtrip_with_cow():
     np.testing.assert_array_equal(np.asarray(state.pool_k[3]), before)
     row = (M - hot) % ps  # boundary row just written in slot 0's page 5
     np.testing.assert_array_equal(
-        np.asarray(state.pool_k[5, row]), np.ones((g, d), np.float32)
+        np.asarray(state.pool_k[5, row]), np.ones((g * d,), np.float32)
     )
 
 
@@ -629,3 +631,138 @@ def test_empty_prompt_rejected_at_validation(setup):
         eng = Engine(cfg, params, hot_cap=4, max_len=64, **kw)
         with pytest.raises(ValueError, match="empty prompt"):
             eng.serve([Request(0, empty.tokens, 4)], slots=1)
+
+
+# ---------------------------------------------------------------------------
+# decode step over the layer-stacked cache: the kernel reads layer l in
+# place, the append writes one row per slot into the carried stack
+# ---------------------------------------------------------------------------
+
+
+def _decode_run(cfg, params, cache, tokens, active):
+    step = jax.jit(lambda c, t: T.decode_step(params, cfg, t, c, mode="packed",
+                                              active=active))
+    logits = []
+    for t in tokens:
+        lg, cache = step(cache, t)
+        logits.append(np.asarray(lg))
+    return np.stack(logits), cache
+
+
+@pytest.mark.kernel_parity
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
+def test_decode_step_stacked_cache_parity(paged):
+    """40 decode steps of a 3-layer dense model: the Pallas impl
+    (interpret mode; flash-decode reads each layer of the stacked cache
+    in place) against the XLA impl (rotate, append, read a copy of the
+    layer). Slot 0 starts empty and crosses the hot -> cold boundary and
+    a page boundary; slots 1 and 2 read one shared prefix page and append
+    into private pages; slot 3 is inactive throughout. Logits agree to
+    the fused-decode parity tolerance; the final caches hold the same
+    rows and lengths, and the shared page and the inactive slot are
+    untouched."""
+    import dataclasses
+
+    base = get_smoke_config("falcon3-1b")
+    cfgs = {impl: dataclasses.replace(
+        base, n_layers=3, bitnet=dataclasses.replace(base.bitnet, impl=impl))
+        for impl in ("pallas", "xla")}
+    params = pack_lib.pack_params(
+        T.init_params(jax.random.PRNGKey(0), cfgs["xla"]), cfgs["xla"])
+    b, hot, ps, max_len, n_steps = 4, 4, 8, 64, 40
+    gd = base.n_kv_heads * base.resolved_head_dim
+    cache = T.init_decode_cache(cfgs["xla"], b, max_len, hot, jnp.float32,
+                                paged=paged, page_size=ps, n_pages=33)["attn"]
+    key = jax.random.PRNGKey(1)
+    n_layers = cache.lengths.shape[0]
+    prefix = hot + ps  # slots 1 and 2: hot tier + one full shared page
+    lengths = jnp.asarray([0, prefix, prefix, 5], jnp.int32)
+    rows = jax.random.normal(key, (n_layers, hot + ps, gd), jnp.float32)
+    hot_k = cache.hot_k.at[:, 1:3].set(rows[:, None, :hot])
+    if paged:
+        shared = 32  # past every slot's private pages (4 x 8)
+        table = cache.page_table.at[:, 2, 0].set(shared)  # slot 2 -> slot 1's
+        table = table.at[:, 1, 0].set(shared)
+        pool_k = cache.pool_k.at[:, shared].set(rows[:, hot:])
+        cache = cache._replace(hot_k=hot_k, hot_v=hot_k, pool_k=pool_k,
+                               pool_v=pool_k, page_table=table)
+    else:
+        cold_k = cache.cold_k.at[:, 1:3, :ps].set(rows[:, None, hot:])
+        cache = cache._replace(hot_k=hot_k, hot_v=hot_k, cold_k=cold_k,
+                               cold_v=cold_k)
+    cache = {"attn": cache._replace(
+        lengths=jnp.broadcast_to(lengths, cache.lengths.shape))}
+    active = jnp.asarray([True, True, True, False])
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (n_steps, b), 0,
+                                base.vocab_size)
+    out = {impl: _decode_run(cfgs[impl], params, cache, tokens, active)
+           for impl in ("pallas", "xla")}
+    (lp, cp), (lx, cx) = out["pallas"], out["xla"]
+    np.testing.assert_allclose(lp[:, :3], lx[:, :3], rtol=5e-5, atol=5e-5)
+    cp, cx = cp["attn"], cx["attn"]
+    want = np.broadcast_to(np.asarray(lengths) + n_steps * np.asarray(active),
+                           (n_layers, b))
+    np.testing.assert_array_equal(np.asarray(cp.lengths), want)
+    np.testing.assert_array_equal(np.asarray(cx.lengths), want)
+    for name in cp._fields:
+        np.testing.assert_allclose(np.asarray(getattr(cp, name)),
+                                   np.asarray(getattr(cx, name)),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    before = cache["attn"]
+    if paged:
+        np.testing.assert_array_equal(np.asarray(cp.pool_k[:, shared]),
+                                      np.asarray(before.pool_k[:, shared]))
+    np.testing.assert_array_equal(np.asarray(cp.hot_k[:, 3]),
+                                  np.asarray(before.hot_k[:, 3]))
+
+
+@pytest.mark.kernel_parity
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
+def test_chunk_step_stacked_cache_parity(paged):
+    """Six chunked-prefill steps of a 3-layer dense model: the Pallas
+    impl (interpret mode; flash-prefill reads each layer of the stacked
+    cache in place, the append scatters the chunk into it) against the
+    XLA impl. Slot 0 prefills from empty across the hot -> cold boundary
+    and two page boundaries, slot 1 continues a prompt mid-page with
+    short chunks, slot 2 idles (n_valid 0). Logits of the prefilling
+    slots agree to the fused-decode parity tolerance; the final caches
+    hold the same rows and lengths, and the idle slot is untouched."""
+    import dataclasses
+
+    base = get_smoke_config("falcon3-1b")
+    cfgs = {impl: dataclasses.replace(
+        base, n_layers=3, bitnet=dataclasses.replace(base.bitnet, impl=impl))
+        for impl in ("pallas", "xla")}
+    params = pack_lib.pack_params(
+        T.init_params(jax.random.PRNGKey(0), cfgs["xla"]), cfgs["xla"])
+    b, hot, ps, max_len, c = 3, 4, 8, 64, 6
+    cache = T.init_decode_cache(cfgs["xla"], b, max_len, hot, jnp.float32,
+                                paged=paged, page_size=ps, n_pages=24)
+    n_valid = [jnp.asarray(v, jnp.int32) for v in
+               ([6, 3, 0], [6, 5, 0], [6, 0, 0], [2, 6, 0], [6, 1, 0],
+                [5, 6, 0])]
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (len(n_valid), b, c),
+                                0, base.vocab_size)
+    out = {}
+    for impl in ("pallas", "xla"):
+        step = jax.jit(lambda cc, t, n, cfg=cfgs[impl]: T.prefill_chunk_step(
+            params, cfg, t, cc, n, mode="packed"))
+        cc, logits = cache, []
+        for t, n in zip(tokens, n_valid):
+            lg, cc = step(cc, t, n)
+            logits.append(np.asarray(lg))
+        out[impl] = (np.stack(logits), cc["attn"])
+    (lp, cp), (lx, cx) = out["pallas"], out["xla"]
+    np.testing.assert_allclose(lp[:, :2], lx[:, :2], rtol=5e-5, atol=5e-5)
+    want = sum(np.asarray(n) for n in n_valid)
+    n_layers = cp.lengths.shape[0]
+    np.testing.assert_array_equal(np.asarray(cp.lengths),
+                                  np.broadcast_to(want, (n_layers, b)))
+    np.testing.assert_array_equal(np.asarray(cx.lengths),
+                                  np.broadcast_to(want, (n_layers, b)))
+    for name in cp._fields:
+        np.testing.assert_allclose(np.asarray(getattr(cp, name)),
+                                   np.asarray(getattr(cx, name)),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    np.testing.assert_array_equal(np.asarray(cp.hot_k[:, 2]),
+                                  np.asarray(cache["attn"].hot_k[:, 2]))

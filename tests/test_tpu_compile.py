@@ -18,9 +18,11 @@ imports every test file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import kv_cache as kvc
@@ -154,13 +156,14 @@ def test_expert_matmul_compiles(one_chip, carried_scale):
 
 
 def _cache(sharding, slots, hot_cap, max_len, dtype, paged):
+    """A cache in the stored (G·HD) layout, abstract on the chip."""
     cold = max_len - hot_cap
     if paged:
         page = ops.default_page_size(H // G, HD, max_len)
         make = lambda: kvc.init_paged_cache(  # noqa: E731
-            slots, hot_cap, cold, (G, HD), dtype, page_size=page)
+            slots, hot_cap, cold, (G * HD,), dtype, page_size=page)
     else:
-        make = lambda: kvc.init_cache(slots, hot_cap, cold, (G, HD), dtype)  # noqa: E731
+        make = lambda: kvc.init_cache(slots, hot_cap, cold, (G * HD,), dtype)  # noqa: E731
     return _on(sharding, jax.eval_shape(make))
 
 
@@ -230,3 +233,91 @@ def test_off_path_kernels_carry_their_names(one_chip, kernel):
                        _spec(one_chip, (slots, h, dl + dr), jnp.bfloat16),
                        _on(one_chip, jax.eval_shape(make)), dl,
                        (192.0) ** -0.5, impl="pallas", interpret=False)
+
+
+# an op of these kinds whose result holds a whole layer's page pool is a
+# per-layer copy of the cache: a slice of a layer, its write-back, or a
+# copy of the stack
+_POOL_COPY_OPS = ("copy", "copy-start", "dynamic-slice",
+                  "dynamic-update-slice")
+_HLO_OP = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _assert_cache_in_place(one_chip, monkeypatch, build):
+    """Compile ``build(cfg, slots) -> (jitted step, its argument specs
+    after params and cache)`` at falcon3-1b widths (2 layers, 12 slots,
+    8k context, a 64-page pool) with the cache donated, and assert the
+    step keeps the stacked KV cache where it lives: its temporaries stay
+    below one layer's pool, and no copy, slice or write-back of a
+    pool-sized buffer is left in the compiled program. The model code
+    picks the Pallas kernels (compiled, not interpreted) by
+    ``jax.default_backend()``, which reads "tpu" here as on the chip."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import pack as pack_lib
+    from repro.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    base = get_config("falcon3-1b")
+    cfg = dataclasses.replace(
+        base, n_layers=2, bitnet=dataclasses.replace(base.bitnet, lora_rank=0))
+    slots, max_len, n_pages = 12, 8192, 64
+    params = jax.eval_shape(lambda: pack_lib.pack_params(
+        T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16), cfg))
+    cache = jax.eval_shape(lambda: T.init_decode_cache(
+        cfg, slots, max_len, 32, jnp.bfloat16, paged=True, page_size=256,
+        n_pages=n_pages))
+    step, specs = build(T, cfg, slots)
+    compiled = step.lower(
+        _on(one_chip, params), _on(one_chip, cache),
+        *[_spec(one_chip, shape, dt) for shape, dt in specs],
+    ).compile()
+    pool = cache["attn"].pool_k
+    layer_pool = pool.size // pool.shape[0]  # elements of one layer's K pool
+    layer_bytes = 2 * layer_pool * pool.dtype.itemsize  # K + V
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    copies = []
+    for name, dims, op in _HLO_OP.findall(text):
+        n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if n >= layer_pool and (
+                op in _POOL_COPY_OPS
+                or any(k in name for k in ("dynamic-slice", "dynamic_slice",
+                                           "dynamic-update-slice",
+                                           "dynamic_update_slice"))
+                or name.startswith("copy")):
+            copies.append(f"{name} {op}[{dims}]")
+    assert not copies, copies
+
+
+def test_decode_step_reads_the_stacked_cache_in_place(one_chip, monkeypatch):
+    """The decode step (``_assert_cache_in_place``): flash-decode reads
+    each layer of the stack in place and the append writes one row per
+    slot into it."""
+
+    def build(T, cfg, slots):
+        step = jax.jit(
+            lambda p, c, t, a: T.decode_step(p, cfg, t, c, mode="packed",
+                                             active=a),
+            donate_argnums=(1,))
+        return step, [((slots,), jnp.int32), ((slots,), jnp.bool_)]
+
+    _assert_cache_in_place(one_chip, monkeypatch, build)
+
+
+def test_chunk_step_reads_the_stacked_cache_in_place(one_chip, monkeypatch):
+    """The chunked-prefill step (``_assert_cache_in_place``), at a
+    16-token chunk so its activations stay small beside the pool:
+    flash-prefill reads each layer of the stack in place and the append
+    scatters the chunk's rows into it."""
+
+    def build(T, cfg, slots):
+        step = jax.jit(
+            lambda p, c, t, n: T.prefill_chunk_step(p, cfg, t, c, n,
+                                                    mode="packed"),
+            donate_argnums=(1,))
+        return step, [((slots, 16), jnp.int32), ((slots,), jnp.int32)]
+
+    _assert_cache_in_place(one_chip, monkeypatch, build)
